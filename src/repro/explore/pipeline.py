@@ -81,6 +81,18 @@ def _digest(*parts: Any) -> str:
     ).hexdigest()[:16]
 
 
+def config_key(cfg: ExploreConfig) -> str:
+    """The config's content key as every record carries it.
+
+    ``on_error`` is left out: it decides what happens to a failing pair,
+    never what a clean pair computes, so a run's records are the same
+    bytes under either policy (the service runs every request isolated).
+    """
+    d = cfg.to_dict()
+    del d["on_error"]
+    return _digest(d)
+
+
 def graph_key(g: Graph) -> str:
     """Stable structural fingerprint of an application graph."""
     nodes = sorted((nid, op, sorted((g.attrs.get(nid) or {}).items()))
@@ -999,7 +1011,7 @@ class Explorer:
                 dict(self.apps), ranked,
                 [fresh(v, sorted(self.apps)) for v in
                  variants[cfg.domain_name]], elapsed)
-        return ExploreResult(cfg, _digest(cfg.to_dict()), dict(self.apps),
+        return ExploreResult(cfg, config_key(cfg), dict(self.apps),
                              results, elapsed,
                              self.sim_buckets(progs) if progs else {},
                              self.metrics.to_dict(), list(self.failures))

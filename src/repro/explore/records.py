@@ -147,6 +147,7 @@ class ExploreRecord:
     schema: int
     mode: str                  # "per_app" | "domain"
     config_key: str            # content key of the producing ExploreConfig
+    #                            (explore.pipeline.config_key: on_error aside)
     n_merged: int              # subgraphs merged into this variant
     sim_bucket: str            # batched-simulate bucket signature ("serial"
     # for the per-pair loop, "" when the pair was not simulated); outputs

@@ -24,7 +24,7 @@ class FabricOptions:
     backend        — annealing engine: "jax" (batched chains) | "python".
     hpwl_backend   — placement cost kernel: "jnp" | "pallas"
                      (:func:`repro.kernels.pnr_cost.hpwl_pallas`, interpret
-                     mode off-TPU).
+                     mode on the ``cpu`` platform only).
     score_mode     — move scoring: "delta" (incremental — rescore only the
                      nets the swap touches; the default and the only mode
                      that scales past ~32x32) | "full" (recompute every

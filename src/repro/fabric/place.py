@@ -242,11 +242,12 @@ def _build_annealer(steps: int, n_pe_c: int, n_io_c: int,
 
     hpwl_backend selects the move-scoring kernel family: ``"jnp"`` (jitted
     jax.numpy reductions) or ``"pallas"`` (the Pallas kernels from
-    :mod:`repro.kernels.pnr_cost`, compiled on TPU and interpreted on CPU
-    hosts).  score_mode selects full recompute (``"full"``, O(N·D) per
-    move) or incremental rescoring of only the touched nets (``"delta"``,
-    O(K·D) per move).  All four combinations compute identical HPWL, so
-    chains accept identical move sequences and return identical placements.
+    :mod:`repro.kernels.pnr_cost`, compiled on TPU and interpreted on CPU,
+    as :func:`repro.kernels.default_interpret` decides).  score_mode
+    selects full recompute (``"full"``, O(N·D) per move) or incremental
+    rescoring of only the touched nets (``"delta"``, O(K·D) per move).  All
+    four combinations compute identical HPWL, so chains accept identical
+    move sequences and return identical placements.
     """
     import jax
     import jax.numpy as jnp
@@ -254,9 +255,8 @@ def _build_annealer(steps: int, n_pe_c: int, n_io_c: int,
     from ..kernels.pnr_cost import (hpwl, hpwl_delta, hpwl_delta_pallas,
                                     hpwl_pallas, net_hpwl)
 
-    interpret = jax.default_backend() != "tpu"
     if hpwl_backend == "pallas":
-        score = functools.partial(hpwl_pallas, interpret=interpret)
+        score = hpwl_pallas
     elif hpwl_backend == "jnp":
         score = hpwl
     else:
@@ -328,7 +328,7 @@ def _build_annealer(steps: int, n_pe_c: int, n_io_c: int,
             if hpwl_backend == "pallas":
                 new_vals, delta = hpwl_delta_pallas(
                     slot_xy, slot_of, net_pins, net_mask, pnc, tn,
-                    ai, b, interpret=interpret)
+                    ai, b)
             else:
                 new_vals, delta = hpwl_delta(slot_xy, cand, net_pins,
                                              net_mask, pnc, tn)

@@ -42,12 +42,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int,
 
     def body(j, carry):
         acc, m, l = carry
-        # scalar leading indices must be pl.ds slices (bare Python ints are
-        # rejected by pl.load's NDIndexer on this JAX version)
-        k = pl.load(k_ref, (pl.ds(0, 1), pl.ds(0, 1), pl.ds(j * bk, bk),
-                            slice(None)))[0, 0].astype(jnp.float32)  # (bk, D)
-        v = pl.load(v_ref, (pl.ds(0, 1), pl.ds(0, 1), pl.ds(j * bk, bk),
-                            slice(None)))[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)  # (bk, D)
+        v = v_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = q @ k.T                                       # (bq, bk)
         if softcap:
             s = softcap * jnp.tanh(s / softcap)

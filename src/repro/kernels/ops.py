@@ -1,8 +1,8 @@
 """Jitted public wrappers around the Pallas kernels (padding + dispatch).
 
-``interpret`` defaults to True on CPU hosts (the kernels TARGET TPU; the
-interpreter executes the kernel bodies in Python for validation) and False
-when a TPU backend is present.
+``interpret`` left unset resolves through :func:`default_interpret`: the
+kernels compile for the TPU, run in the Pallas interpreter on the ``cpu``
+platform (the test suite's path), and refuse any other platform.
 """
 
 from __future__ import annotations
@@ -20,14 +20,29 @@ from .mamba_scan import mamba_scan
 from .pe_fused import kernel_from_config, make_pe_kernel
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def default_interpret() -> bool:
+    """Interpret mode for a Pallas call whose caller left it unset.
+
+    The one place that decides it: False on ``tpu`` (compile the kernel),
+    True on ``cpu`` (the interpreter, for tests), and an error on any other
+    platform, so a kernel never silently runs interpreted on a host that
+    was meant to compile it.  Callers that pass ``interpret=`` explicitly
+    keep their choice.
+    """
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for TPU only and interpret on CPU; "
+        f"platform {platform!r} has neither path")
 
 
 def fused_pe_apply(pattern: Graph, *inputs, block=(256, 256),
                    interpret: Optional[bool] = None):
     """Apply a mined/merged PE pattern elementwise over the inputs."""
-    interp = _default_interpret() if interpret is None else interpret
+    interp = default_interpret() if interpret is None else interpret
     fn = make_pe_kernel(pattern, block=block, interpret=interp)
     return fn(*inputs)
 
@@ -35,7 +50,7 @@ def fused_pe_apply(pattern: Graph, *inputs, block=(256, 256),
 def attention(q, k, v, *, causal=True, window=0, softcap=0.0, scale=0.0,
               bq=128, bk=128, interpret: Optional[bool] = None):
     """Padded flash attention; q (B,Hq,S,D), k/v (B,Hkv,S,D)."""
-    interp = _default_interpret() if interpret is None else interpret
+    interp = default_interpret() if interpret is None else interpret
     b, hq, s, d = q.shape
     blk = max(min(bq, s), min(bk, s))
     pad = (-s) % blk
@@ -54,7 +69,7 @@ def attention(q, k, v, *, causal=True, window=0, softcap=0.0, scale=0.0,
 def selective_scan(a, bx, c, *, bs=128, bd=128,
                    interpret: Optional[bool] = None):
     """Padded chunked mamba scan; a/bx (B,S,D,N), c (B,S,N) -> y (B,S,D)."""
-    interp = _default_interpret() if interpret is None else interpret
+    interp = default_interpret() if interpret is None else interpret
     b, s, d, n = a.shape
     pad_s = (-s) % min(bs, max(s, 1))
     pad_d = (-d) % min(bd, max(d, 1))
@@ -71,7 +86,7 @@ def matmul_fused(x, w, *extras, epilogue=None, extra_kinds=(),
                  bm=128, bn=128, bk=128, out_dtype=None,
                  interpret: Optional[bool] = None):
     """Padded MXU matmul with fused PE epilogue."""
-    interp = _default_interpret() if interpret is None else interpret
+    interp = default_interpret() if interpret is None else interpret
     m, k = x.shape
     _, n = w.shape
     pm, pk, pn = (-m) % min(bm, m), (-k) % min(bk, k), (-n) % min(bn, n)

@@ -13,7 +13,8 @@ Full-recompute implementations:
   path used inside the annealing loop;
 * :func:`hpwl_batched` — vmapped over a leading chain axis;
 * :func:`hpwl_pallas` — Pallas kernel over the padded per-net coordinate
-  matrices (interpret mode on CPU hosts; compiles for TPU VMEM tiles).
+  matrices, one block of :data:`HPWL_BLOCK_NETS` nets per grid step
+  (interpret mode on CPU hosts; compiles for TPU VMEM tiles).
 
 Delta (incremental) implementations — a swap move touches only the nets
 incident to the two swapped entities, so the annealer's hot loop rescopes
@@ -47,11 +48,14 @@ A pure-NumPy oracle (:func:`hpwl_reference`) anchors the tests.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from .ops import default_interpret
 
 _BIG = 1e9
 
@@ -117,26 +121,44 @@ def _hpwl_kernel(x_ref, y_ref, m_ref, o_ref):
     o_ref[...] = jnp.where(valid, (xmax - xmin) + (ymax - ymin), 0.0)
 
 
+#: nets per grid step of :func:`hpwl_pallas`: bounds the kernel's VMEM
+#: footprint (three (block, D) int32/float32 tiles, double-buffered) however
+#: many nets the fabric has — all ~14k nets of a 128x128 array at once do
+#: not fit in VMEM
+HPWL_BLOCK_NETS = 512
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def hpwl_pallas(pos: jax.Array, net_pins: jax.Array, net_mask: jax.Array,
-                *, interpret: bool = True) -> jax.Array:
+                *, interpret: Optional[bool] = None) -> jax.Array:
     """Total HPWL via a Pallas reduction kernel.
 
     Gathers pin coordinates outside the kernel (gathers are host-side
     cheap; the reduction is the VPU-shaped part), pads the pin matrices to
-    TPU tile multiples (8 x 128 for float32), and reduces per net.
+    TPU tile multiples (rows to the net block, pins to 128 lanes), reduces
+    one block of nets per grid step, and sums the per-net costs.  On
+    integer coordinates the sum is exact, so the result equals
+    :func:`hpwl` bit for bit.  ``interpret`` left unset follows
+    :func:`~repro.kernels.default_interpret`.
     """
-    from .tiling import pad2d, round_up, SUBLANE
+    from .tiling import SUBLANE, pad2d, round_up
 
+    if interpret is None:
+        interpret = default_interpret()
     n, d = net_pins.shape
+    bn = min(HPWL_BLOCK_NETS, round_up(n, SUBLANE))
     xy = pos[net_pins].astype(jnp.float32)           # (N, D, 2)
-    x = pad2d(xy[..., 0])
-    y = pad2d(xy[..., 1])
-    m = pad2d(net_mask.astype(jnp.int32))
+    x = pad2d(xy[..., 0], rows=bn)
+    y = pad2d(xy[..., 1], rows=bn)
+    m = pad2d(net_mask.astype(jnp.int32), rows=bn)
+    n_pad, d_pad = m.shape
+    block = pl.BlockSpec((bn, d_pad), lambda i: (i, 0))
     per_net = pl.pallas_call(
         _hpwl_kernel,
-        out_shape=jax.ShapeDtypeStruct((round_up(n, SUBLANE), 1),
-                                       jnp.float32),
+        grid=(n_pad // bn,),
+        in_specs=[block, block, block],
+        out_specs=pl.BlockSpec((bn, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
         interpret=interpret,
     )(x, y, m)
     return jnp.sum(per_net)
@@ -213,7 +235,7 @@ def hpwl_delta_pallas(slot_xy: jax.Array, slot_of: jax.Array,
                       net_pins: jax.Array, net_mask: jax.Array,
                       per_net_cost: jax.Array, touched: jax.Array,
                       ent_a: jax.Array, ent_b: jax.Array,
-                      *, interpret: bool = True):
+                      *, interpret: Optional[bool] = None):
     """Same contract as :func:`hpwl_delta`, but scores *the swap of
     ent_a/ent_b applied to slot_of* without materializing the candidate
     permutation: the touched nets' pre-swap coordinates stay resident in
@@ -221,6 +243,8 @@ def hpwl_delta_pallas(slot_xy: jax.Array, slot_of: jax.Array,
     """
     from .tiling import pad2d, round_up, SUBLANE
 
+    if interpret is None:
+        interpret = default_interpret()
     pins, mask, old = _touched_view(net_pins, net_mask, per_net_cost,
                                     touched)
     t = pins.shape[0]

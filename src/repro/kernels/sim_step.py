@@ -27,12 +27,14 @@ suite: add/sub/mul/min/max/shift/compare/select).
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict, Sequence, Tuple
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from .ops import default_interpret
 
 #: (a, b, c) -> result, all float32; must mirror graphir.interp.SEMANTICS
 ALU_IMPLS: Dict[str, Callable] = {
@@ -157,16 +159,19 @@ def _build_step_kernel(ops: Tuple[str, ...]):
 @functools.partial(jax.jit, static_argnames=("ops", "interpret"))
 def alu_step_pallas(codes: jax.Array, a: jax.Array, b: jax.Array,
                     c: jax.Array, ops: Tuple[str, ...],
-                    *, interpret: bool = True) -> jax.Array:
+                    *, interpret: Optional[bool] = None) -> jax.Array:
     """Compute-all-select dispatch as a Pallas VPU kernel.
 
     Operands are padded to float32 tile multiples (8 x 128); the batch axis
     maps onto sublanes, tiles onto lanes.  Division/transcendental branches
     run on every lane and are masked out by the opcode select — standard
-    SIMD divergence handling, no flow control in the kernel.
+    SIMD divergence handling, no flow control in the kernel.  ``interpret``
+    left unset follows :func:`~repro.kernels.default_interpret`.
     """
     from .tiling import LANE, SUBLANE, round_up
 
+    if interpret is None:
+        interpret = default_interpret()
     shape = a.shape
     a2 = a.reshape(-1, shape[-1]).astype(jnp.float32)
     b2 = b.reshape(-1, shape[-1]).astype(jnp.float32)

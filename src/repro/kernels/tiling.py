@@ -24,9 +24,10 @@ def pow2_bucket(n: int) -> int:
     return 1 << max(0, (n - 1)).bit_length()
 
 
-def pad2d(x, fill=0):
+def pad2d(x, fill=0, rows=SUBLANE):
     """Zero-copy-where-possible pad of a 2-D array to the float32 VMEM tile
-    grid (rows to a SUBLANE multiple, cols to a LANE multiple).
+    grid (rows to a multiple of ``rows`` — SUBLANE, or a kernel's row block
+    — and cols to a LANE multiple).
 
     Returns the padded array; ``fill`` seeds the padding region (0 for data
     whose pad rows must reduce to the masked identity).
@@ -34,7 +35,7 @@ def pad2d(x, fill=0):
     import jax.numpy as jnp
 
     r, c = x.shape
-    rp, cp = round_up(r, SUBLANE), round_up(c, LANE)
+    rp, cp = round_up(r, rows), round_up(c, LANE)
     if (rp, cp) == (r, c):
         return x
     out = jnp.full((rp, cp), fill, dtype=x.dtype)

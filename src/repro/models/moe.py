@@ -65,7 +65,7 @@ def moe_mlp_shardmap(x: jax.Array, params: Dict[str, jax.Array],
     outputs over `model` (plus its identity-cost transpose in backward):
     per layer ~|activations| bytes instead of ~|dispatch buffer| bytes.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     e_pad = params["w_router"].shape[1]
@@ -119,7 +119,7 @@ def moe_mlp_shardmap(x: jax.Array, params: Dict[str, jax.Array],
         in_specs=(bp, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=bp,
-        check_rep=False,
+        check_vma=False,
     )(x, params["w_router"], params["wg"], params["wu"], params["wd"])
 
     # shared experts stay on the plain pjit path (dense, replicated weights)

@@ -14,6 +14,11 @@ step; this module forwards them — when enabled — to
   time (the span *ends* when the listener fires; its start is backdated
   by the reported duration).
 
+The persistent compilation cache's own events (``/jax/compilation_cache/
+cache_hits``, ``cache_misses``, ...) land as counters
+``jax.compilation_cache.<leaf>``, so a run can tell a warm cache from a
+cold one.
+
 ``jax.monitoring`` has no per-listener unregister (only a global
 ``clear_event_listeners``), so the listener is installed once and
 consults a module flag — :func:`disable` flips the flag, it does not
@@ -35,10 +40,13 @@ _REGISTRY: Optional[MetricsRegistry] = None
 
 # substrings of jax.monitoring event names worth accounting for
 _COMPILE_MARK = "compile"
+_CACHE_PREFIX = "/jax/compilation_cache/"
 
 
 def _on_duration(event: str, duration: float, **kwargs) -> None:
-    if not _ENABLED or _COMPILE_MARK not in event:
+    # the cache's "compile_time_saved_sec" is time NOT spent compiling
+    if (not _ENABLED or _COMPILE_MARK not in event
+            or event.startswith(_CACHE_PREFIX)):
         return
     reg = _REGISTRY or global_registry()
     leaf = event.rstrip("/").rsplit("/", 1)[-1]
@@ -52,6 +60,13 @@ def _on_duration(event: str, duration: float, **kwargs) -> None:
                             track="jax-compile", event=event)
 
 
+def _on_event(event: str, **kwargs) -> None:
+    if not _ENABLED or not event.startswith(_CACHE_PREFIX):
+        return
+    reg = _REGISTRY or global_registry()
+    reg.inc(f"jax.compilation_cache.{event[len(_CACHE_PREFIX):]}")
+
+
 def enable(registry: Optional[MetricsRegistry] = None) -> bool:
     """Start forwarding jax compile events; returns False if jax is
     missing (the subsystem stays a no-op)."""
@@ -63,6 +78,7 @@ def enable(registry: Optional[MetricsRegistry] = None) -> bool:
         except Exception:       # pragma: no cover - jax is baked in
             return False
         monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
         _INSTALLED = True
     _ENABLED = True
     return True

@@ -36,8 +36,8 @@ __all__ = ["MANIFEST_SCHEMA", "RunManifest", "capture", "validate_manifest"]
 #: bump on any field add/rename/retype; validators reject other versions
 MANIFEST_SCHEMA = 1
 
-#: legal xla_cache states: "off" (no cache dir configured), "cold" (dir
-#: configured but absent/empty at capture time), "warm" (dir has entries)
+#: legal xla_cache states: "off" (no cache dir in use), "cold" (dir in use
+#: but absent/empty at capture time), "warm" (dir has entries)
 XLA_CACHE_STATES = ("off", "cold", "warm")
 
 
@@ -97,7 +97,11 @@ def _jax_fields() -> Dict[str, str]:
 
 
 def _xla_cache_state() -> str:
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    """State of the cache directory JAX actually uses: the
+    ``jax_compilation_cache_dir`` setting, which ``JAX_COMPILATION_CACHE_DIR``
+    or :func:`repro.compile_cache.use_compile_cache` set."""
+    import jax
+    cache_dir = jax.config.jax_compilation_cache_dir
     if not cache_dir:
         return "off"
     try:

@@ -198,4 +198,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from ..compile_cache import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

@@ -101,8 +101,7 @@ class ExploreService:
         t0 = time.perf_counter()
         if request.config.on_error != "isolate":
             # a batched stranger must never fail-fast its batchmates;
-            # note this changes the config (and record config_key) the
-            # request is served under — serving always runs isolated
+            # records keep their bytes (config_key leaves on_error out)
             request = ServeRequest(
                 rid=request.rid, apps=request.apps,
                 config=request.config.replace(on_error="isolate"))

@@ -71,7 +71,7 @@ def make_compressed_grad_transform(mesh: Mesh, dp_axes: Tuple[str, ...],
     residual is returned for the caller to carry (optional simple mode drops
     it; the trainer example carries it).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def transform(grads):
         def leaf_allreduce(g, spec):
@@ -83,7 +83,7 @@ def make_compressed_grad_transform(mesh: Mesh, dp_axes: Tuple[str, ...],
             return shard_map(
                 body, mesh=mesh,
                 in_specs=(in_spec,), out_specs=in_spec,
-                check_rep=False)(g)
+                check_vma=False)(g)
 
         return jax.tree.map(
             lambda g, s: leaf_allreduce(g, s), grads, param_specs,

@@ -19,7 +19,7 @@ from typing import Any, Callable, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -82,7 +82,7 @@ def gpipe(stage_fn: Callable[[Any, jax.Array], jax.Array],
             _inner, mesh=mesh,
             in_specs=(p_spec, P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(stage_params, x_micro)
 
     return apply

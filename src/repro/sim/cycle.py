@@ -358,8 +358,6 @@ def _build_stepper(prog: SimProgram, iterations: int, backend: str,
 
     from ..kernels.sim_step import alu_step_jnp, alu_step_pallas
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     K = iterations
     ii = prog.ii
     U = prog.n_steps

@@ -45,7 +45,7 @@ def test_compressed_allreduce_matches_mean():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.sharding.compression import compressed_psum
         mesh = jax.make_mesh((8,), ("data",))
@@ -53,7 +53,7 @@ def test_compressed_allreduce_matches_mean():
                         jnp.float32)
         got = shard_map(lambda xl: compressed_psum(xl, ("data",)),
                         mesh=mesh, in_specs=(P("data"),),
-                        out_specs=P("data"), check_rep=False)(x)
+                        out_specs=P("data"), check_vma=False)(x)
         # every shard receives the (quantized) mean over shards
         want = jnp.broadcast_to(jnp.mean(x, axis=0, keepdims=True),
                                 x.shape)

@@ -107,6 +107,22 @@ def test_config_rejects_bad_values():
         ExploreConfig(rank_mode="nope")
 
 
+def test_records_same_bytes_under_either_failure_policy():
+    # the service runs every request isolated; a clean run's records must
+    # equal a fail-fast solo run's byte for byte (config_key drops on_error)
+    from repro.explore.__main__ import _smoke_case
+    from repro.explore.pipeline import config_key
+
+    apps, cfg = _smoke_case()
+    lines = {}
+    for policy in ("isolate", "raise"):
+        res = Explorer(apps, cfg.replace(on_error=policy)).run()
+        assert res.clean
+        lines[policy] = [json.dumps(r.to_dict()) for r in res.records()]
+    assert lines["isolate"] and lines["isolate"] == lines["raise"]
+    assert config_key(cfg) != config_key(cfg.replace(max_merge=1))
+
+
 # ---------------------------------------------------------------------------
 # stage memoization
 # ---------------------------------------------------------------------------

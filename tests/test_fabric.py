@@ -10,9 +10,9 @@ from repro.fabric import (FabricSpec, extract_netlist, place,
                           place_and_route, route_nets, synthetic_netlist)
 from repro.fabric.place import anneal_jax, anneal_python, lower, \
     net_incidence
-from repro.kernels.pnr_cost import (hpwl, hpwl_batched, hpwl_delta,
-                                    hpwl_delta_pallas, hpwl_pallas,
-                                    hpwl_reference, net_hpwl)
+from repro.kernels.pnr_cost import (HPWL_BLOCK_NETS, hpwl, hpwl_batched,
+                                    hpwl_delta, hpwl_delta_pallas,
+                                    hpwl_pallas, hpwl_reference, net_hpwl)
 
 SPEC = FabricSpec(rows=8, cols=8)
 
@@ -147,6 +147,23 @@ def test_hpwl_jax_matches_python_reference(harris):
         got_pl = float(hpwl_pallas(pos, problem.net_pins, problem.net_mask,
                                    interpret=True))
         assert got_pl == pytest.approx(want)
+
+
+def test_hpwl_pallas_tiled_equals_hpwl():
+    # a 32x32 synthetic netlist has more nets than one kernel block, so
+    # the grid runs several steps; integer coordinates sum exactly
+    spec = FabricSpec(rows=32, cols=32)
+    problem = lower(synthetic_netlist(spec, seed=3), spec)
+    assert problem.net_pins.shape[0] > HPWL_BLOCK_NETS
+    rng = np.random.default_rng(11)
+    slot_of = np.concatenate([
+        rng.permutation(problem.n_pe_slots),
+        problem.n_pe_slots + rng.permutation(problem.n_io_slots)])
+    pos = problem.slot_xy[slot_of]
+    want = hpwl(pos, problem.net_pins, problem.net_mask)
+    got = hpwl_pallas(pos, problem.net_pins, problem.net_mask,
+                      interpret=True)
+    assert float(got) == float(want)
 
 
 def test_hpwl_batched_matches_per_chain(harris):

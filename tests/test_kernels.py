@@ -7,8 +7,8 @@ import numpy as np
 import pytest
 
 from repro.graphir import pattern_from_spec
-from repro.kernels import (attention, fused_pe_apply, matmul_fused,
-                           selective_scan)
+from repro.kernels import (attention, default_interpret, fused_pe_apply,
+                           matmul_fused, selective_scan)
 from repro.kernels.ref import (ref_attention, ref_gemm_pe, ref_mamba_scan,
                                ref_pe)
 
@@ -151,3 +151,30 @@ def test_mined_pattern_to_kernel_end_to_end():
     exps = exp if isinstance(exp, tuple) else (exp,)
     for o, e in zip(outs, exps):
         np.testing.assert_allclose(np.asarray(o, np.float64), e, rtol=1e-5)
+
+
+def test_default_interpret_only_on_cpu(monkeypatch):
+    assert default_interpret() is True            # the tests' cpu platform
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert default_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        default_interpret()
+
+
+@pytest.mark.parametrize("kernel", ["hpwl_pallas", "alu_step_pallas"])
+def test_kernel_interpret_default_follows_platform(kernel, monkeypatch):
+    # a kernel called without interpret= never picks the interpreter on
+    # its own: an unknown platform is refused at trace time
+    from repro.kernels.pnr_cost import hpwl_pallas
+    from repro.kernels.sim_step import alu_step_pallas
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        if kernel == "hpwl_pallas":     # an (N, D) shape no other test uses
+            hpwl_pallas(jnp.zeros((5, 2)), jnp.zeros((3, 7), jnp.int32),
+                        jnp.ones((3, 7), bool))
+        else:
+            x = jnp.zeros((3, 5))
+            alu_step_pallas(jnp.zeros((5,), jnp.int32), x, x, x,
+                            ("nop", "add"))

@@ -222,6 +222,53 @@ def test_jaxprof_counts_compiles_into_registry():
     assert reg.counter("jax.compile.events") == before
 
 
+def test_jaxprof_counts_compilation_cache_events():
+    from jax import monitoring
+    reg = MetricsRegistry()
+    obs.jaxprof.enable(registry=reg)
+    try:
+        monitoring.record_event("/jax/compilation_cache/cache_hits")
+        monitoring.record_event("/jax/compilation_cache/cache_misses")
+        monitoring.record_event("/jax/compilation_cache/cache_misses")
+        # time the cache saved is not compile time
+        monitoring.record_event_duration_secs(
+            "/jax/compilation_cache/compile_time_saved_sec", 5.0)
+    finally:
+        obs.jaxprof.disable()
+    assert reg.counter("jax.compilation_cache.cache_hits") == 1
+    assert reg.counter("jax.compilation_cache.cache_misses") == 2
+    assert reg.histogram("jax.compile.secs").count == 0
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    assert reg.counter("jax.compilation_cache.cache_hits") == 1
+
+
+def test_compile_cache_env_wins_else_repo_default(monkeypatch, tmp_path):
+    import jax
+
+    from repro.compile_cache import DEFAULT_CACHE_DIR, use_compile_cache
+    from repro.obs.manifest import _xla_cache_state
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert use_compile_cache() == str(DEFAULT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_CACHE_DIR)
+        assert DEFAULT_CACHE_DIR.name == ".jax_cache"
+        assert (DEFAULT_CACHE_DIR.parent / "pyproject.toml").exists()
+        # the manifest reads the directory JAX uses, not the variable
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        assert _xla_cache_state() == "cold"
+        (tmp_path / "entry").write_text("x")
+        assert _xla_cache_state() == "warm"
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert _xla_cache_state() == "off"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 # ---------------------------------------------------------------------------
 # pipeline integration: memo accounting, shared stores
 # ---------------------------------------------------------------------------
