@@ -185,20 +185,23 @@ def pnr_grouped(items: List[Tuple[str, Any, Mapping, Graph, int]],
     spec0 = options.spec or FabricSpec()
     lowered: List[Optional[Tuple]] = []
     errors: Dict[int, Exception] = {}
-    for i, (pe_name, dp, mapping, app, nonce) in enumerate(items):
-        try:
-            faultinject.fire("pnr", pe=pe_name, app=mapping.app_name)
-            netlist = extract_netlist(mapping, app, spec0)
-            spec = spec0.fit(len(netlist.pe_cells), len(netlist.io_cells))
-            prob = lower(netlist, spec)
-            check_anneal_budget(prob, options.chains, options.sweeps,
-                                options.anneal_max_states, metrics=registry)
-            lowered.append((netlist, spec, prob))
-        except Exception as e:
-            if not isolate:
-                raise
-            lowered.append(None)
-            errors[i] = e
+    with span("pnr.lower", pairs=len(items)):
+        for i, (pe_name, dp, mapping, app, nonce) in enumerate(items):
+            try:
+                faultinject.fire("pnr", pe=pe_name, app=mapping.app_name)
+                netlist = extract_netlist(mapping, app, spec0)
+                spec = spec0.fit(len(netlist.pe_cells),
+                                 len(netlist.io_cells))
+                prob = lower(netlist, spec)
+                check_anneal_budget(prob, options.chains, options.sweeps,
+                                    options.anneal_max_states,
+                                    metrics=registry)
+                lowered.append((netlist, spec, prob))
+            except Exception as e:
+                if not isolate:
+                    raise
+                lowered.append(None)
+                errors[i] = e
 
     groups: Dict[Tuple, List[int]] = defaultdict(list)
     for i, low in enumerate(lowered):

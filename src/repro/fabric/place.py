@@ -622,11 +622,26 @@ def anneal_jax_batch(problems: List[PlacementProblem], *, chains: int = 16,
     land in ``metrics`` (histogram ``pnr.anneal.accept_rate``, cost curves
     as ``pnr.anneal.cost_curve.<nonce>`` gauges), defaulting to the global
     registry.
+
+    Always, each call observes two histograms in that registry, the
+    annealer's work: ``pnr.anneal.steps_real`` = chains x the sum over
+    problems of ``max(1, sweeps x (PE + IO cells))``, the steps that can
+    move a cell, and ``pnr.anneal.steps_run`` = problems x chains x
+    ``s_pad``, the steps the device runs: ``s_pad`` is the trip count of
+    the compiled ``fori_loop`` of :func:`_build_batch_annealer` in both
+    score modes, and steps past a problem's own count are masked to
+    rejects.  Traced, the caller's enclosing span (``pnr.dispatch`` on the
+    explore path) gets ``problems``, ``chains``, ``s_pad``,
+    ``steps_real``, ``steps_run`` and the real ``cells``, ``nets`` and
+    ``pins`` summed over the problems, and the call itself opens
+    ``pnr.pack`` (padding, chain init, key derivation), ``pnr.device``
+    (the program, until its outputs are numpy arrays) and ``pnr.unpack``
+    (telemetry and slicing).
     """
     import jax
 
     from ..kernels.pnr_cost import EMPTY_BOX
-    from ..obs import telemetry_enabled
+    from ..obs import current_span, span, telemetry_enabled
     from ..obs.metrics import global_registry
 
     if telemetry is None:
@@ -647,69 +662,86 @@ def anneal_jax_batch(problems: List[PlacementProblem], *, chains: int = 16,
 
     n_p = len(problems)
     has_fix = any(p.net_fix is not None for p in problems)
-    net_pins = np.zeros((n_p, n_pad, d_pad), np.int32)
-    net_mask = np.zeros((n_p, n_pad, d_pad), bool)
-    net_fix = (np.tile(np.asarray(EMPTY_BOX, np.float32), (n_p, n_pad, 1))
-               if has_fix else None)
-    slot_xy = np.zeros((n_p, e_pad, 2), np.float32)
-    ent_nets = np.full((n_p, e_pad, k_pad), n_pad, np.int32)
-    dims = np.zeros((n_p, 5), np.int32)
-    t0s = np.zeros((n_p,), np.float32)
-    init = np.tile(np.arange(e_pad, dtype=np.int32), (n_p, chains, 1))
-    keys = np.zeros((n_p, chains, 2), np.uint32)
-    base_key = jax.random.PRNGKey(seed)
-    for i, p in enumerate(problems):
-        n, d = p.net_pins.shape
-        net_pins[i, :n, :d] = p.net_pins
-        net_mask[i, :n, :d] = p.net_mask
-        if p.net_fix is not None:
-            net_fix[i, :n] = p.net_fix
-        e = p.n_entities
-        slot_xy[i, :e] = p.slot_xy
-        en = np.where(p.ent_nets == n, n_pad, p.ent_nets)
-        ent_nets[i, :e, :en.shape[1]] = en
-        n_real = p.n_pe_cells + p.n_io_cells
-        dims[i] = (p.n_pe_cells, p.n_io_cells, p.n_pe_slots, p.n_io_slots,
-                   max(1, sweeps * n_real))
-        t0s[i] = _default_t0(p) if t0 is None else t0
-        rng = _random.Random(seed)
-        for c in range(chains):
-            init[i, c, :e] = _init_slots(p, rng)
-        keys[i] = np.asarray(jax.random.split(
-            jax.random.fold_in(base_key, nonces[i] & 0x7FFFFFFF), chains))
+    steps_real = chains * sum(max(1, sweeps * (p.n_pe_cells + p.n_io_cells))
+                              for p in problems)
+    steps_run = n_p * chains * s_pad
+    reg = metrics if metrics is not None else global_registry()
+    reg.observe("pnr.anneal.steps_real", steps_real)
+    reg.observe("pnr.anneal.steps_run", steps_run)
+    enclosing = current_span()
+    if enclosing is not None:
+        enclosing.attrs.update(
+            problems=n_p, chains=chains, s_pad=s_pad, steps_real=steps_real,
+            steps_run=steps_run,
+            cells=sum(p.n_pe_cells + p.n_io_cells for p in problems),
+            nets=sum(int(p.net_pins.shape[0]) for p in problems),
+            pins=sum(int(p.net_mask.sum()) for p in problems))
 
-    run = _build_batch_annealer(s_pad, n_pad, d_pad, e_pad, k_pad,
-                                float(t1), "jnp", score_mode,
-                                bool(telemetry), has_fix)
-
-    def flat(x):                     # (P, C, ...) -> (P*C, ...)
-        return x.reshape((n_p * chains,) + x.shape[2:])
-
-    def tile(x):                     # (P, ...) -> (P*C, ...) per-chain copy
-        return np.repeat(x, chains, axis=0)
-
-    args = (flat(keys), flat(init), tile(slot_xy),
-            tile(net_pins), tile(net_mask), tile(ent_nets),
-            tile(dims), tile(t0s))
-    if has_fix:
-        args = args + (tile(net_fix),)
-    out = run(*args)
-    slots = np.asarray(out[0]).reshape(n_p, chains, e_pad)
-    costs = np.asarray(out[1]).reshape(n_p, chains)
-    if telemetry:
-        reg = metrics if metrics is not None else global_registry()
-        accepts = np.asarray(out[2]).reshape(n_p, chains)
-        curves = np.asarray(out[3]).reshape(n_p, chains, CURVE_POINTS)
+    with span("pnr.pack"):
+        net_pins = np.zeros((n_p, n_pad, d_pad), np.int32)
+        net_mask = np.zeros((n_p, n_pad, d_pad), bool)
+        net_fix = (np.tile(np.asarray(EMPTY_BOX, np.float32),
+                           (n_p, n_pad, 1)) if has_fix else None)
+        slot_xy = np.zeros((n_p, e_pad, 2), np.float32)
+        ent_nets = np.full((n_p, e_pad, k_pad), n_pad, np.int32)
+        dims = np.zeros((n_p, 5), np.int32)
+        t0s = np.zeros((n_p,), np.float32)
+        init = np.tile(np.arange(e_pad, dtype=np.int32), (n_p, chains, 1))
+        keys = np.zeros((n_p, chains, 2), np.uint32)
+        base_key = jax.random.PRNGKey(seed)
         for i, p in enumerate(problems):
-            steps_i = max(1, sweeps * (p.n_pe_cells + p.n_io_cells))
-            reg.observe("pnr.anneal.accept_rate",
-                        float(accepts[i].mean()) / steps_i)
-            best_chain = int(np.argmin(costs[i]))
-            reg.set_gauge(f"pnr.anneal.cost_curve.{nonces[i] & 0x7FFFFFFF}",
-                          [round(float(c), 3) for c in
-                           curves[i, best_chain]])
-    return [(slots[i, :, :p.n_entities], costs[i])
-            for i, p in enumerate(problems)]
+            n, d = p.net_pins.shape
+            net_pins[i, :n, :d] = p.net_pins
+            net_mask[i, :n, :d] = p.net_mask
+            if p.net_fix is not None:
+                net_fix[i, :n] = p.net_fix
+            e = p.n_entities
+            slot_xy[i, :e] = p.slot_xy
+            en = np.where(p.ent_nets == n, n_pad, p.ent_nets)
+            ent_nets[i, :e, :en.shape[1]] = en
+            n_real = p.n_pe_cells + p.n_io_cells
+            dims[i] = (p.n_pe_cells, p.n_io_cells, p.n_pe_slots,
+                       p.n_io_slots, max(1, sweeps * n_real))
+            t0s[i] = _default_t0(p) if t0 is None else t0
+            rng = _random.Random(seed)
+            for c in range(chains):
+                init[i, c, :e] = _init_slots(p, rng)
+            keys[i] = np.asarray(jax.random.split(jax.random.fold_in(
+                base_key, nonces[i] & 0x7FFFFFFF), chains))
+
+        run = _build_batch_annealer(s_pad, n_pad, d_pad, e_pad, k_pad,
+                                    float(t1), "jnp", score_mode,
+                                    bool(telemetry), has_fix)
+
+        def flat(x):                 # (P, C, ...) -> (P*C, ...)
+            return x.reshape((n_p * chains,) + x.shape[2:])
+
+        def tile(x):                 # (P, ...) -> (P*C, ...) per-chain copy
+            return np.repeat(x, chains, axis=0)
+
+        args = (flat(keys), flat(init), tile(slot_xy),
+                tile(net_pins), tile(net_mask), tile(ent_nets),
+                tile(dims), tile(t0s))
+        if has_fix:
+            args = args + (tile(net_fix),)
+    with span("pnr.device"):
+        out = run(*args)
+        slots = np.asarray(out[0]).reshape(n_p, chains, e_pad)
+        costs = np.asarray(out[1]).reshape(n_p, chains)
+    with span("pnr.unpack"):
+        if telemetry:
+            accepts = np.asarray(out[2]).reshape(n_p, chains)
+            curves = np.asarray(out[3]).reshape(n_p, chains, CURVE_POINTS)
+            for i, p in enumerate(problems):
+                steps_i = max(1, sweeps * (p.n_pe_cells + p.n_io_cells))
+                reg.observe("pnr.anneal.accept_rate",
+                            float(accepts[i].mean()) / steps_i)
+                best_chain = int(np.argmin(costs[i]))
+                reg.set_gauge(
+                    f"pnr.anneal.cost_curve.{nonces[i] & 0x7FFFFFFF}",
+                    [round(float(c), 3) for c in curves[i, best_chain]])
+        return [(slots[i, :, :p.n_entities], costs[i])
+                for i, p in enumerate(problems)]
 
 
 def place(netlist: Netlist, spec: FabricSpec, *, backend: str = "jax",

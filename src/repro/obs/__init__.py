@@ -4,8 +4,10 @@ Zero-dependency and off by default — instrumented code paths cost ~one
 dict lookup when nothing is enabled, and enabling them never changes a
 computed bit (CI-tested).  Three cooperating pieces:
 
-* :mod:`repro.obs.trace` — nested span tree, Chrome trace-event /
-  flat-jsonl export (``span("pnr", variant=..., app=...)``);
+* :mod:`repro.obs.trace` — nested span tree per thread and asyncio
+  task, Chrome trace-event / flat-jsonl export
+  (``span("pnr", variant=..., app=...)``), mirrored on the
+  ``jax.profiler`` clock while tracing is on;
 * :mod:`repro.obs.metrics` — process-local counters/gauges/histograms;
   ``Explorer.stats`` is a :class:`~repro.obs.metrics.CounterView` over
   an explorer-owned registry;
@@ -53,16 +55,17 @@ def telemetry_enabled() -> bool:
 
 from .metrics import (CounterView, Histogram, MetricsRegistry,
                       global_registry, reset_global_registry)
-from .trace import (Span, Tracer, current as current_tracer,
-                    disable as disable_tracing, enable as enable_tracing,
-                    event, span)
+from .trace import (Span, Tracer, async_span, current_span,
+                    current as current_tracer, disable as disable_tracing,
+                    enable as enable_tracing, event, record_span, span)
 from .manifest import RunManifest, capture as capture_manifest
 from .diff import (NoiseModel, StageDelta, diff_metrics, diff_traces,
                    summarize_repeats)
 from . import diff, history, manifest, memprof
 
 __all__ = [
-    "span", "event", "enable_tracing", "disable_tracing", "current_tracer",
+    "span", "async_span", "event", "record_span", "current_span",
+    "enable_tracing", "disable_tracing", "current_tracer",
     "Span", "Tracer",
     "MetricsRegistry", "CounterView", "Histogram", "global_registry",
     "reset_global_registry",

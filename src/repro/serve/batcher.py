@@ -48,7 +48,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from ..explore import ExploreResult, Explorer
 from ..explore.pipeline import graph_key
 from ..explore.records import ExploreRecord, StageFailure
-from ..obs import event as obs_event, span
+from ..obs import Span, current_span, event as obs_event, record_span, span
 from ..obs.metrics import MetricsRegistry
 from .protocol import ServeRequest
 
@@ -110,8 +110,9 @@ class _Ticket:
     group: str                       # batch group: the config digest
     solo: bool                       # domain mode: never share a batch
     future: "asyncio.Future[Tuple[list, list]]"
-    enqueued: float                  # loop.time() at admission
+    enqueued: float                  # time.perf_counter() at admission
     app_keys: Dict[str, str] = field(default_factory=dict)
+    parent: Optional[Span] = None    # traced: the request's open span
 
 
 class ContinuousBatcher:
@@ -196,7 +197,6 @@ class ContinuousBatcher:
         hit = self._cache.get(key)
         if hit is not None:
             self.metrics.inc("serve.cache_hit")
-            obs_event("serve.cache_hit", rid=request.rid)
             return hit[0], hit[1], True
 
         fut = self._inflight.get(key)
@@ -219,8 +219,9 @@ class ContinuousBatcher:
         ticket = _Ticket(
             request=request, key=key,
             group=key[0], solo=(cfg.mode != "per_app"),
-            future=fut, enqueued=loop.time(),
-            app_keys={n: graph_key(g) for n, g in request.apps.items()})
+            future=fut, enqueued=time.perf_counter(),
+            app_keys={n: graph_key(g) for n, g in request.apps.items()},
+            parent=current_span())
         self._inflight[key] = fut
         self._pending.append(ticket)
         self._wake.set()
@@ -239,7 +240,7 @@ class ContinuousBatcher:
                     continue
                 await self._wake.wait()
                 continue
-            now = loop.time()
+            now = time.perf_counter()
             batch = self._select_batch(now)
             if batch is None:
                 oldest = min(t.enqueued for t in self._pending)
@@ -290,13 +291,15 @@ class ContinuousBatcher:
         return batch or None
 
     async def _flush(self, batch: List[_Ticket], loop) -> None:
-        now = loop.time()
+        now = time.perf_counter()
         for t in batch:
             self._pending.remove(t)
             self._depth -= 1
             self._slots.release()
             self.metrics.observe("serve.time_in_queue_ms",
                                  (now - t.enqueued) * 1e3)
+            record_span("serve.queue", t.enqueued, now, t.parent,
+                        rid=t.request.rid)
         self.metrics.set_gauge("serve.queue_depth", self._depth)
         self.metrics.inc("serve.batches")
         self.metrics.observe("serve.batch_tickets", len(batch))
@@ -348,7 +351,8 @@ class ContinuousBatcher:
             merged.update(t.request.apps)
         cfg = batch[0].request.config         # group key = config digest
         ex = Explorer(merged, cfg, store=self._store, metrics=self.metrics)
-        with span("serve.batch", tickets=len(batch), apps=len(merged)):
+        with span("serve.batch", tickets=len(batch), apps=len(merged),
+                  rids=[t.request.rid for t in batch]):
             result = ex.run()
         return [([r.to_dict() for r in ticket_records(result, t.request)],
                  [f.to_dict() for f in ticket_failures(result, t.request)])

@@ -31,7 +31,7 @@ from typing import Any, Dict, Optional, Union
 
 from ..explore import ExploreConfig
 from ..graphir.graph import Graph
-from ..obs import event as obs_event
+from ..obs import async_span, span
 from ..obs.metrics import MetricsRegistry
 from .batcher import ContinuousBatcher, QueueFull
 from .protocol import (ProtocolError, ServeRequest, ServeResponse,
@@ -113,16 +113,12 @@ class ExploreService:
         except Exception as e:
             self.metrics.observe("serve.request_ms",
                                  (time.perf_counter() - t0) * 1e3)
-            obs_event("serve.request_failed", rid=request.rid,
-                      error=type(e).__name__)
             return ServeResponse(rid=request.rid, ok=False,
                                  error=f"{type(e).__name__}: {e}")
         elapsed_ms = (time.perf_counter() - t0) * 1e3
         self.metrics.observe("serve.request_ms", elapsed_ms)
         if cached:
             self.metrics.observe("serve.cache_hit_ms", elapsed_ms)
-        obs_event("serve.request_done", rid=request.rid, cached=cached,
-                  records=len(records), failures=len(failures))
         return ServeResponse(rid=request.rid, ok=True, records=records,
                              failures=failures, cached=cached,
                              elapsed_ms=elapsed_ms)
@@ -130,34 +126,48 @@ class ExploreService:
     # -- wire protocol -----------------------------------------------------
     async def handle_line(self, line: Union[str, bytes]) -> Dict[str, Any]:
         """One NDJSON request line -> one response object (a dict)."""
-        try:
-            obj = json.loads(line)
-        except (json.JSONDecodeError, UnicodeDecodeError) as e:
-            self.metrics.inc("serve.protocol_errors")
-            return ServeResponse(rid="", ok=False,
-                                 error=f"bad JSON: {e}").to_dict()
-        try:
-            request = parse_request_line(obj)
-        except ProtocolError as e:
-            self.metrics.inc("serve.protocol_errors")
-            rid = obj.get("id", "") if isinstance(obj, dict) else ""
-            return ServeResponse(rid=str(rid), ok=False,
-                                 error=str(e)).to_dict()
-        resp = await self.submit_request(request)
-        return resp.to_dict()
+        return (await self._respond(line)).to_dict()
+
+    async def _respond(self, line: Union[str, bytes]) -> ServeResponse:
+        with span("serve.decode"):
+            try:
+                obj = json.loads(line)
+            except (json.JSONDecodeError, UnicodeDecodeError) as e:
+                self.metrics.inc("serve.protocol_errors")
+                return ServeResponse(rid="", ok=False,
+                                     error=f"bad JSON: {e}")
+            try:
+                request = parse_request_line(obj)
+            except ProtocolError as e:
+                self.metrics.inc("serve.protocol_errors")
+                rid = obj.get("id", "") if isinstance(obj, dict) else ""
+                return ServeResponse(rid=str(rid), ok=False, error=str(e))
+        return await self.submit_request(request)
 
     async def _serve_stream(self, reader: asyncio.StreamReader,
                             write_line) -> None:
         """Shared connection loop: requests on a connection run
         concurrently (that's the point of batching), responses are
-        serialized through ``write_lock`` in completion order."""
+        serialized through ``write_lock`` in completion order.
+
+        Traced, each line is one ``serve.request`` span (``rid``, ``ok``,
+        ``cached``, ``records``, ``failures``) holding ``serve.decode``,
+        the batcher's ``serve.queue`` and ``serve.encode``."""
         write_lock = asyncio.Lock()
         tasks = set()
 
         async def one(line: bytes) -> None:
-            d = await self.handle_line(line)
-            async with write_lock:
-                await write_line(json.dumps(d) + "\n")
+            with async_span("serve.request") as req:
+                resp = await self._respond(line)
+                with async_span("serve.encode"):
+                    text = json.dumps(resp.to_dict()) + "\n"
+                    async with write_lock:
+                        await write_line(text)
+                if req is not None:
+                    req.attrs.update(
+                        rid=resp.rid, ok=resp.ok, cached=resp.cached,
+                        records=len(resp.records),
+                        failures=len(resp.failures))
 
         self.metrics.inc("serve.connections")
         while True:

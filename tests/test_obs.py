@@ -73,7 +73,7 @@ def test_span_exception_safety(tracer):
     spans = {path: sp for sp, _, path in tracer.iter_spans()}
     assert set(spans) == {"outer", "outer/inner"}
     assert spans["outer/inner"].error == "ValueError: boom"
-    assert not tracer._stack
+    assert tracer.open_spans() == ()
     # the tracer still works afterwards
     with obs.span("after"):
         pass
