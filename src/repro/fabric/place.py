@@ -421,12 +421,29 @@ def _build_batch_annealer(s_pad: int, n_pad: int, d_pad: int, e_pad: int,
     are frozen boxes rather than entities.  Sentinel (:data:`EMPTY_BOX`)
     rows make the fixed fold a bit-exact no-op, so box-free nets score
     identically to the plain program.
+
+    The delta-mode loop body is *dense*: it has no gather or scatter,
+    which a TPU runs one element at a time.  Every per-chain dynamic index
+    is a compare against an iota followed by a select or a masked
+    reduction over the ``e_pad`` entities or the ``n_pad`` nets, so all
+    chains of a dispatch advance in vector lanes.  It carries each net's
+    pin coordinates in the loop state (pins x nets, the nets along the
+    lanes), applies a swap to them by compare and select, rescores every
+    net from them, and reads the touched nets' old and new costs, deduped
+    in the order :attr:`PlacementProblem.ent_nets` lists them, by one-hot
+    reductions.  HPWL values are small multiples of 0.5, exact in
+    float32, and each one-hot reduction has at most one non-zero term, so
+    the moves, accept tests and costs are bit-identical to the full-score
+    loop body's, which gathers and scatters.  On a TPU v5e the dense body
+    beat an indexed delta body (touched pins gathered, their costs
+    scattered) at every padded entity count from 512 to 16384, and tied
+    with it at 32768 (PERF.md).
     """
     import jax
     import jax.numpy as jnp
 
-    from ..kernels.pnr_cost import (hpwl, hpwl_delta, hpwl_delta_fixed,
-                                    hpwl_fixed, net_hpwl, net_hpwl_fixed)
+    from ..kernels.pnr_cost import (hpwl, hpwl_fixed, net_hpwl,
+                                    net_hpwl_fixed, net_hpwl_pins)
 
     if hpwl_backend != "jnp":
         raise ValueError("anneal_jax_batch supports hpwl_backend='jnp' only "
@@ -442,20 +459,12 @@ def _build_batch_annealer(s_pad: int, n_pad: int, d_pad: int, e_pad: int,
 
             def per_net_cost(pos):
                 return net_hpwl_fixed(pos, net_pins, net_mask, net_fix)
-
-            def delta_cost(cand, pnc, tn):
-                return hpwl_delta_fixed(slot_xy, cand, net_pins, net_mask,
-                                        pnc, tn, net_fix)
         else:
             def total_cost(pos):
                 return hpwl(pos, net_pins, net_mask)
 
             def per_net_cost(pos):
                 return net_hpwl(pos, net_pins, net_mask)
-
-            def delta_cost(cand, pnc, tn):
-                return hpwl_delta(slot_xy, cand, net_pins, net_mask,
-                                  pnc, tn)
         n_pe_c, n_io_c, n_pe_s, n_io_s, n_steps = (
             dims[0], dims[1], dims[2], dims[3], dims[4])
         n_real = jnp.maximum(n_pe_c + n_io_c, 1)
@@ -487,7 +496,8 @@ def _build_batch_annealer(s_pad: int, n_pad: int, d_pad: int, e_pad: int,
             n_acc, curve = tele
             n_acc = n_acc + accept.astype(jnp.int32)
             idx = jnp.minimum((i * CURVE_POINTS) // s_pad, CURVE_POINTS - 1)
-            return n_acc, curve.at[idx].set(cur)
+            return n_acc, jnp.where(jnp.arange(CURVE_POINTS) == idx, cur,
+                                    curve)
 
         def accept_and_track(accept, cand, new, state_rest):
             slot_of, cur, best_slot, best = state_rest
@@ -521,45 +531,74 @@ def _build_batch_annealer(s_pad: int, n_pad: int, d_pad: int, e_pad: int,
                 return out[2], out[3], out[4], out[5]
             return out[2], out[3]
 
+        iota_e = jnp.arange(e_pad, dtype=jnp.int32)
+        iota_n = jnp.arange(n_pad, dtype=jnp.int32)
         k2_ = k_pad * 2
         dup_tri = jnp.tril(jnp.ones((k2_, k2_), bool), k=-1)
+        slot_x, slot_y = slot_xy[:, 0], slot_xy[:, 1]           # (E,)
+        ent_t = ent_nets.T                                      # (K, E)
+        pins_t, mask_t = net_pins.T, net_mask.T                 # (D, N)
+        fix = tuple(net_fix.T) if fixed else None               # 4 x (N,)
+
+        def pick(hit, x):
+            # x where hit along the last axis (one match at most), else 0
+            return jnp.sum(jnp.where(hit, x, 0), axis=-1)
 
         def step(i, state):
-            slot_of, pnc, cur, best_slot, best = state[:5]
+            px, py, pnc, slot_of, cur, best_slot, best = state[:7]
             ai, ti = a[i], t[i]
-            b = jnp.argmax(slot_of == ti)
-            cand = slot_of.at[ai].set(slot_of[b]).at[b].set(slot_of[ai])
-            tn = jnp.concatenate([ent_nets[ai], ent_nets[b]])
+            b = jnp.argmax(slot_of == ti)       # occupant of target slot
+            at_a, at_b = iota_e == ai, iota_e == b
+            # slot_of permutes all e_pad slots, so b sits on ti: a takes
+            # ti and b takes a's slot, and their pins move with them
+            s_a = pick(at_a, slot_of)
+            cand = jnp.where(at_a, ti, jnp.where(at_b, s_a, slot_of))
+            to_t, to_a = iota_e == ti, iota_e == s_a
+            on_a, on_b = pins_t == ai, pins_t == b
+            cpx = jnp.where(on_a, pick(to_t, slot_x),
+                            jnp.where(on_b, pick(to_a, slot_x), px))
+            cpy = jnp.where(on_a, pick(to_t, slot_y),
+                            jnp.where(on_b, pick(to_a, slot_y), py))
+            cand_pnc = net_hpwl_pins(cpx, cpy, mask_t, fix, axis=0)
+            # nets incident to either swapped entity, deduped so a net
+            # touching both contributes its delta exactly once
+            tn = jnp.concatenate([pick(at_a, ent_t), pick(at_b, ent_t)])
             dup = jnp.any((tn[:, None] == tn[None, :]) & dup_tri, axis=1)
-            tn = jnp.where(dup, n_pad, tn)
-            new_vals, delta = delta_cost(cand, pnc, tn)
-            new = cur + delta
+            hit = (jnp.where(dup, n_pad, tn)[:, None]
+                   == iota_n)                                   # (T, N)
+            new = cur + jnp.sum(pick(hit, cand_pnc) - pick(hit, pnc))
             accept = ((new <= cur)
                       | (log_u[i] * temps[i] < cur - new)) & active[i]
-            pnc = jnp.where(accept,
-                            pnc.at[tn].set(new_vals, mode="drop"), pnc)
-            slot_of, cur, best_slot, best = accept_and_track(
+            # an untouched net rescores to its old cost exactly, so taking
+            # every net's candidate cost writes the touched nets' alone
+            pnc = jnp.where(accept, cand_pnc, pnc)
+            px = jnp.where(accept, cpx, px)
+            py = jnp.where(accept, cpy, py)
+            out = (px, py, pnc) + accept_and_track(
                 accept, cand, new, (slot_of, cur, best_slot, best))
             if telemetry:
-                tele = tele_track(i, accept, cur, state[5:])
-                return (slot_of, pnc, cur, best_slot, best) + tele
-            return slot_of, pnc, cur, best_slot, best
+                return out + tele_track(i, accept, out[4], state[7:])
+            return out
 
-        pnc0 = per_net_cost(slot_xy[slot_of0])
+        pos0 = slot_xy[slot_of0]
+        pnc0 = per_net_cost(pos0)
         c0 = jnp.sum(pnc0)
-        state0 = (slot_of0, pnc0, c0, slot_of0, c0)
+        pin_xy0 = pos0[net_pins]                                # (N, D, 2)
+        state0 = (pin_xy0[..., 0].T, pin_xy0[..., 1].T, pnc0,
+                  slot_of0, c0, slot_of0, c0)
         if telemetry:
             state0 = state0 + tele0()
         out = jax.lax.fori_loop(0, s_pad, step, state0)
         if telemetry:
-            return out[3], out[4], out[5], out[6]
-        return out[3], out[4]
+            return out[5], out[6], out[7], out[8]
+        return out[5], out[6]
 
     # one flat vmap over problems x chains, each row carrying its own
-    # problem data: a nested vmap (outer problems, inner chains with the
-    # problem arrays broadcast) would avoid the per-chain copies but
-    # measures ~2x slower end to end on the Fig. 11 suite, so the copies
-    # (a few MB at these sizes) buy the better-vectorizing flat batch
+    # problem data (copies of a few MB at these sizes): a nested vmap
+    # (outer problems, inner chains with the problem arrays broadcast)
+    # would avoid the copies; it measured ~2x slower end to end on the
+    # Fig. 11 suite on a CPU with the indexed loop body, and has not been
+    # timed with the dense one or on a TPU
     return jax.jit(jax.vmap(chain))
 
 
@@ -630,10 +669,14 @@ def anneal_jax_batch(problems: List[PlacementProblem], *, chains: int = 16,
     ``s_pad``, the steps the device runs: ``s_pad`` is the trip count of
     the compiled ``fori_loop`` of :func:`_build_batch_annealer` in both
     score modes, and steps past a problem's own count are masked to
-    rejects.  Traced, the caller's enclosing span (``pnr.dispatch`` on the
-    explore path) gets ``problems``, ``chains``, ``s_pad``,
-    ``steps_real``, ``steps_run`` and the real ``cells``, ``nets`` and
-    ``pins`` summed over the problems, and the call itself opens
+    rejects.  It also counts itself as ``pnr.anneal.dense_dispatches``
+    (delta scoring: the loop body has no gather or scatter, see
+    :func:`_build_batch_annealer`) or ``pnr.anneal.indexed_dispatches``
+    (full scoring, whose loop body gathers and scatters).  Traced, the
+    caller's enclosing span (``pnr.dispatch`` on the explore path) gets
+    ``problems``, ``chains``, ``s_pad``, ``steps_real``, ``steps_run``,
+    ``step_form`` and the real ``cells``, ``nets`` and ``pins`` summed
+    over the problems, and the call itself opens
     ``pnr.pack`` (padding, chain init, key derivation), ``pnr.device``
     (the program, until its outputs are numpy arrays) and ``pnr.unpack``
     (telemetry and slicing).
@@ -665,14 +708,16 @@ def anneal_jax_batch(problems: List[PlacementProblem], *, chains: int = 16,
     steps_real = chains * sum(max(1, sweeps * (p.n_pe_cells + p.n_io_cells))
                               for p in problems)
     steps_run = n_p * chains * s_pad
+    form = "dense" if score_mode == "delta" else "indexed"
     reg = metrics if metrics is not None else global_registry()
     reg.observe("pnr.anneal.steps_real", steps_real)
     reg.observe("pnr.anneal.steps_run", steps_run)
+    reg.inc(f"pnr.anneal.{form}_dispatches")
     enclosing = current_span()
     if enclosing is not None:
         enclosing.attrs.update(
             problems=n_p, chains=chains, s_pad=s_pad, steps_real=steps_real,
-            steps_run=steps_run,
+            steps_run=steps_run, step_form=form,
             cells=sum(p.n_pe_cells + p.n_io_cells for p in problems),
             nets=sum(int(p.net_pins.shape[0]) for p in problems),
             pins=sum(int(p.net_mask.sum()) for p in problems))
@@ -899,7 +944,7 @@ def place_hierarchical(netlist: Netlist, spec: FabricSpec, *,
        tiles — all clusters *simultaneously*, grouped by
        :func:`batch_signature` into giant pow2-bucketed vmapped
        dispatches.  External pins enter as per-net fixed boxes in the
-       cluster's local frame (:func:`repro.kernels.pnr_cost.hpwl_delta_fixed`).
+       cluster's local frame (:func:`repro.kernels.pnr_cost.net_hpwl_pins`).
     5. **Deblock**: cells within ``deblock_halo`` tiles of a region seam
        re-anneal jointly across the seams at low temperature.
 

@@ -21,7 +21,10 @@ incident to the two swapped entities, so the annealer's hot loop rescopes
 those ≤2K nets instead of all N:
 
 * :func:`hpwl_delta` — jnp path: gather only the touched nets' pins under
-  the candidate permutation and rescore them;
+  the candidate permutation and rescore them (the serial annealer; the
+  batched one, :func:`repro.fabric.place._build_batch_annealer`, carries
+  pin coordinates instead and rescores them with :func:`net_hpwl_pins`,
+  with no gather or scatter in its loop);
 * :func:`hpwl_delta_pallas` — fused Pallas variant: pre-swap pin
   coordinates go to VMEM and the kernel *applies the swap in-kernel*
   (select on the two swapped entity ids) before reducing the per-net
@@ -36,9 +39,7 @@ pins, rebased into the cluster frame) that is folded into the per-net
 reduction:
 
 * :func:`net_hpwl_fixed` / :func:`hpwl_fixed` — full recompute with the
-  fixed boxes folded in;
-* :func:`hpwl_delta_fixed` — the incremental counterpart of
-  :func:`hpwl_delta`;
+  fixed boxes folded in (:func:`net_hpwl_pins` takes them too);
 * :data:`EMPTY_BOX` — the "no external pins" sentinel (min > max, so the
   box never widens a bound and a box-only net scores 0).
 
@@ -76,16 +77,33 @@ def hpwl_reference(pos: np.ndarray, net_pins: np.ndarray,
     return total
 
 
+def net_hpwl_pins(x: jax.Array, y: jax.Array, net_mask: jax.Array,
+                  fix=None, *, axis: int = -1) -> jax.Array:
+    """Per-net HPWL from per-pin coordinates.
+
+    x, y: float pin coordinates and net_mask: bool, all one shape with the
+    pins along ``axis`` (the nets along the rest); fix: None, or the
+    per-net fixed boxes as four arrays (xmin, xmax, ymin, ymax) shaped
+    like the result, folded into each net's bounds.  A net is scored
+    when it has a masked-in pin or a non-empty box; others score 0.
+    """
+    xmin = jnp.min(jnp.where(net_mask, x, _BIG), axis=axis)
+    xmax = jnp.max(jnp.where(net_mask, x, -_BIG), axis=axis)
+    ymin = jnp.min(jnp.where(net_mask, y, _BIG), axis=axis)
+    ymax = jnp.max(jnp.where(net_mask, y, -_BIG), axis=axis)
+    valid = jnp.any(net_mask, axis=axis)
+    if fix is not None:
+        fx0, fx1, fy0, fy1 = fix
+        xmin, xmax = jnp.minimum(xmin, fx0), jnp.maximum(xmax, fx1)
+        ymin, ymax = jnp.minimum(ymin, fy0), jnp.maximum(ymax, fy1)
+        valid = valid | (fx0 <= fx1)
+    return jnp.where(valid, (xmax - xmin) + (ymax - ymin), 0.0)
+
+
 def net_hpwl_from_xy(xy: jax.Array, net_mask: jax.Array) -> jax.Array:
     """Per-net HPWL from already-gathered pin coordinates.
     xy: (N, D, 2) float; net_mask: (N, D) bool.  Returns (N,)."""
-    x, y = xy[..., 0], xy[..., 1]
-    xmin = jnp.min(jnp.where(net_mask, x, _BIG), axis=-1)
-    xmax = jnp.max(jnp.where(net_mask, x, -_BIG), axis=-1)
-    ymin = jnp.min(jnp.where(net_mask, y, _BIG), axis=-1)
-    ymax = jnp.max(jnp.where(net_mask, y, -_BIG), axis=-1)
-    valid = jnp.any(net_mask, axis=-1)
-    return jnp.where(valid, (xmax - xmin) + (ymax - ymin), 0.0)
+    return net_hpwl_pins(xy[..., 0], xy[..., 1], net_mask)
 
 
 def net_hpwl(pos: jax.Array, net_pins: jax.Array,
@@ -290,30 +308,14 @@ def fixed_box(points) -> np.ndarray:
                        pts[:, 1].min(), pts[:, 1].max()], np.float32)
 
 
-def net_hpwl_fixed_from_xy(xy: jax.Array, net_mask: jax.Array,
-                           net_fix: jax.Array) -> jax.Array:
-    """Per-net HPWL with per-net fixed boxes folded in.
-    xy: (N, D, 2); net_mask: (N, D) bool; net_fix: (N, 4).  Returns (N,).
-    A net is scored when it has movable pins or a non-empty box."""
-    x, y = xy[..., 0], xy[..., 1]
-    xmin = jnp.minimum(jnp.min(jnp.where(net_mask, x, _BIG), axis=-1),
-                       net_fix[..., 0])
-    xmax = jnp.maximum(jnp.max(jnp.where(net_mask, x, -_BIG), axis=-1),
-                       net_fix[..., 1])
-    ymin = jnp.minimum(jnp.min(jnp.where(net_mask, y, _BIG), axis=-1),
-                       net_fix[..., 2])
-    ymax = jnp.maximum(jnp.max(jnp.where(net_mask, y, -_BIG), axis=-1),
-                       net_fix[..., 3])
-    valid = (jnp.any(net_mask, axis=-1)
-             | (net_fix[..., 0] <= net_fix[..., 1]))
-    return jnp.where(valid, (xmax - xmin) + (ymax - ymin), 0.0)
-
-
 def net_hpwl_fixed(pos: jax.Array, net_pins: jax.Array, net_mask: jax.Array,
                    net_fix: jax.Array) -> jax.Array:
     """Per-net HPWL under fixed boxes.  Same contract as :func:`net_hpwl`
-    plus ``net_fix`` (N, 4)."""
-    return net_hpwl_fixed_from_xy(pos[net_pins], net_mask, net_fix)
+    plus ``net_fix`` (N, 4); a net is scored when it has movable pins or a
+    non-empty box."""
+    xy = pos[net_pins]
+    return net_hpwl_pins(xy[..., 0], xy[..., 1], net_mask,
+                         tuple(net_fix[..., j] for j in range(4)))
 
 
 @jax.jit
@@ -321,22 +323,3 @@ def hpwl_fixed(pos: jax.Array, net_pins: jax.Array, net_mask: jax.Array,
                net_fix: jax.Array) -> jax.Array:
     """Total HPWL of one placement with fixed terminals (scalar)."""
     return jnp.sum(net_hpwl_fixed(pos, net_pins, net_mask, net_fix))
-
-
-def hpwl_delta_fixed(slot_xy: jax.Array, cand_slot_of: jax.Array,
-                     net_pins: jax.Array, net_mask: jax.Array,
-                     per_net_cost: jax.Array, touched: jax.Array,
-                     net_fix: jax.Array):
-    """Rescore the ``touched`` nets under fixed boxes — the incremental
-    counterpart of :func:`hpwl_delta`, same contract plus ``net_fix``."""
-    pins, mask, old = _touched_view(net_pins, net_mask, per_net_cost,
-                                    touched)
-    n = net_pins.shape[0]
-    tc = jnp.minimum(touched, n - 1)
-    # pad/duplicate rows are fully masked with old=0; their clamped gather
-    # would still pull net n-1's real box, so force those boxes empty too
-    fix = jnp.where((touched < n)[:, None], net_fix[tc],
-                    jnp.asarray(EMPTY_BOX, net_fix.dtype))
-    xy = slot_xy[cand_slot_of[pins]]                  # (T, D, 2)
-    new_vals = net_hpwl_fixed_from_xy(xy, mask, fix)
-    return new_vals, jnp.sum(new_vals - old)

@@ -77,6 +77,60 @@ def test_batch_annealer_compiles_32x32(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
 
 
+def _while_body_ops(hlo: str) -> set:
+    """Opcodes of every instruction the compiled program's ``while`` body
+    runs, through the fusions and other computations it calls."""
+    import re
+
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    body, = set(re.findall(r" while\(.*body=%?([\w.-]+)", hlo))
+    ops, seen, todo = set(), set(), [body]
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            op = re.search(r"= [^=]*? ([a-z][\w-]*)\(", line)
+            if op:
+                ops.add(op.group(1))
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition)=%?([\w.-]+)", line)
+    return ops
+
+
+def test_batch_annealer_loop_body_has_no_gather_or_scatter(chip):
+    """The annealer at the ml16 cells' first dispatch (6 problems x 16
+    chains, signature (2048, 64, 2, 512, 4)) compiles its loop body to
+    compares, selects and reductions: no indexed read or write per chain
+    step, which a TPU runs one element at a time."""
+    from repro.fabric.place import _build_batch_annealer
+
+    s_pad, n_pad, d_pad, e_pad, k_pad = 2048, 64, 2, 512, 4
+    run = _build_batch_annealer(s_pad, n_pad, d_pad, e_pad, k_pad, 0.02,
+                                "jnp", "delta")
+    rows = 6 * CHAINS
+    compiled = run.lower(
+        _spec(chip, (rows, 2), jnp.uint32),
+        _spec(chip, (rows, e_pad), jnp.int32),
+        _spec(chip, (rows, e_pad, 2), jnp.float32),
+        _spec(chip, (rows, n_pad, d_pad), jnp.int32),
+        _spec(chip, (rows, n_pad, d_pad), jnp.bool_),
+        _spec(chip, (rows, e_pad, k_pad), jnp.int32),
+        _spec(chip, (rows, 5), jnp.int32),
+        _spec(chip, (rows,), jnp.float32)).compile()
+    ops = _while_body_ops(compiled.as_text())
+    assert {"compare", "select"} <= ops
+    assert not ops & {"gather", "scatter"}, sorted(ops)
+
+
 def test_batch_stepper_compiles_fig11_bucket(chip):
     from repro.sim.cycle import _ARITY_PAD, _build_batch_stepper
 

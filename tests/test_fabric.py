@@ -348,3 +348,133 @@ def test_dse_fabric_integration():
     assert c.fabric_wirelength == f.wirelength_hops
     # array view adds interconnect: array e/op dominates PE-core e/op
     assert f.energy_per_op_pj > c.energy_per_op_pj
+
+
+# ---------------------------------------------------------------------------
+# the batched annealer's loop bodies: dense (one-hot) and indexed
+# ---------------------------------------------------------------------------
+def _chain_problem(seed, fixed):
+    """A 16x16 problem with the ``ml16`` signature ``(..., 64, 2, 512, 4)``
+    at 32 sweeps: a random chain of 40 PEs fed by 4 inputs and tapped by 2
+    outputs, every net 2 pins; with ``fixed``, half the nets also carry a
+    fixed box, as the hierarchical placer's sub-problems do."""
+    from repro.fabric.netlist import Cell, Net, Netlist
+    from repro.kernels.pnr_cost import EMPTY_BOX
+
+    spec = FabricSpec(rows=16, cols=16)
+    rng = np.random.default_rng(seed)
+    order = [f"pe{i}" for i in rng.permutation(40)]
+    nl = Netlist(f"chain{seed}")
+    for i in range(40):
+        nl.cells[f"pe{i}"] = Cell(f"pe{i}", "pe", instance=i)
+    for j in range(4):
+        nl.cells[f"in{j}"] = Cell(f"in{j}", "io_in", signals=[j])
+    for j in range(2):
+        nl.cells[f"out{j}"] = Cell(f"out{j}", "io_out", signals=[4 + j])
+    nets = [(a, b) for a, b in zip(order, order[1:])]
+    nets += [(f"in{j}", order[int(k)])
+             for j, k in enumerate(rng.choice(40, 4, replace=False))]
+    nets += [(order[-1], "out0"), (order[int(rng.integers(39))], "out1")]
+    for i, (a, b) in enumerate(nets):
+        nl.nets.append(Net(f"n{i:03d}", a, [b], signal=6 + i))
+    p = lower(nl, spec)
+    if fixed:
+        n = p.net_pins.shape[0]
+        p.net_fix = np.tile(np.asarray(EMPTY_BOX, np.float32), (n, 1))
+        boxed = rng.random(n) < 0.5
+        lo = rng.integers(0, 16, (int(boxed.sum()), 2)).astype(np.float32)
+        p.net_fix[boxed] = np.stack([lo[:, 0] - 0.5, lo[:, 0] + 1.5,
+                                     lo[:, 1], lo[:, 1] + 2.0], axis=1)
+    return p
+
+
+def _anneal_traced(monkeypatch, problems, **kw):
+    """anneal_jax_batch under a ``pnr.dispatch`` span; returns its result,
+    every raw output of the compiled program (accept counts and cost
+    curves too, with telemetry), the registry and the dispatch span."""
+    import importlib
+
+    from repro import obs
+    from repro.fabric import anneal_jax_batch
+    from repro.obs.metrics import MetricsRegistry
+
+    place_mod = importlib.import_module("repro.fabric.place")
+    build = place_mod._build_batch_annealer
+    raw = []
+
+    def spy(*sig):
+        run = build(*sig)
+
+        def call(*args):
+            out = run(*args)
+            raw.extend(np.asarray(o) for o in out)
+            return out
+        return call
+
+    monkeypatch.setattr(place_mod, "_build_batch_annealer", spy)
+    reg = MetricsRegistry()
+    obs.disable_tracing()
+    tracer = obs.enable_tracing()
+    try:
+        with obs.span("pnr.dispatch"):
+            out = anneal_jax_batch(problems, metrics=reg, **kw)
+    finally:
+        obs.disable_tracing()
+    dispatch, = [sp for sp, _, _ in tracer.iter_spans()
+                 if sp.name == "pnr.dispatch"]
+    return out, raw, reg, dispatch
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("telemetry", [False, True])
+@pytest.mark.parametrize("fixed", [False, True])
+def test_dense_delta_step_bit_identical_to_indexed_full_step(
+        monkeypatch, seed, telemetry, fixed):
+    """The batched annealer's two loop bodies at the ml16 signature: the
+    dense delta step (compares, selects and masked reductions) and the
+    indexed full-score step (gathers and scatters) give equal placements,
+    costs, accept counts and cost curves, and the counter and the
+    dispatch span's ``step_form`` name the body that ran."""
+    from repro.fabric import batch_signature
+
+    probs = [_chain_problem(10 * seed + i, fixed) for i in range(2)]
+    assert {batch_signature(p, 32)[1:] for p in probs} == {(64, 2, 512, 4)}
+    kw = dict(chains=3, seed=seed, sweeps=4, nonces=[5 + seed, 9],
+              telemetry=telemetry)
+    runs = {}
+    for mode, form in (("delta", "dense"), ("full", "indexed")):
+        out, raw, reg, dispatch = _anneal_traced(monkeypatch, probs,
+                                                 score_mode=mode, **kw)
+        assert dispatch.attrs["step_form"] == form
+        assert reg.counters("pnr.anneal.") == {
+            f"pnr.anneal.{form}_dispatches": 1}
+        assert len(raw) == (4 if telemetry else 2)
+        runs[form] = (out, raw)
+    (out_d, raw_d), (out_i, raw_i) = runs["dense"], runs["indexed"]
+    for got, want in zip(raw_d, raw_i):
+        assert got.dtype == want.dtype
+        assert np.array_equal(got, want)
+    for (s_d, c_d), (s_i, c_i), p in zip(out_d, out_i, probs):
+        assert np.array_equal(s_d, s_i) and np.array_equal(c_d, c_i)
+        if not fixed:
+            for c in range(3):
+                assert float(c_d[c]) == hpwl_reference(
+                    p.slot_xy[s_d[c]], p.net_pins, p.net_mask)
+
+
+@pytest.mark.parametrize("score_mode, form", [("delta", "dense"),
+                                              ("full", "indexed")])
+def test_step_form_follows_score_mode(monkeypatch, score_mode, form):
+    """Past the ml16 cells' padded entity count (2048 here, not 512) delta
+    scoring still runs the dense loop body and full scoring the indexed
+    one; the counter and the span say which ran."""
+    from repro.fabric import batch_signature
+
+    spec = FabricSpec(rows=32, cols=32)
+    p = lower(synthetic_netlist(spec, seed=1, fill=0.1), spec)
+    assert batch_signature(p, 1)[3] == 2048
+    _, _, reg, dispatch = _anneal_traced(monkeypatch, [p], chains=2,
+                                         sweeps=1, telemetry=False,
+                                         score_mode=score_mode)
+    assert dispatch.attrs["step_form"] == form
+    assert reg.counter(f"pnr.anneal.{form}_dispatches") == 1
