@@ -13,7 +13,8 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
-__all__ = ["BudgetExceeded", "InjectedFault", "StoreCorruption"]
+__all__ = ["BudgetExceeded", "InjectedFault", "StoreCorruption",
+           "Unroutable"]
 
 
 class BudgetExceeded(RuntimeError):
@@ -37,3 +38,13 @@ class InjectedFault(RuntimeError):
 class StoreCorruption(ValueError):
     """A persistent-store entry failed its checksum / decode — the entry
     is quarantined and recomputed, never trusted."""
+
+
+class Unroutable(RuntimeError):
+    """No placement of a pair routes within its channels' track counts:
+    the router ended every try with channels over capacity.  ``overflow``
+    is the fewest tracks over capacity that any try left."""
+
+    def __init__(self, message: str, overflow: int) -> None:
+        super().__init__(message)
+        self.overflow = int(overflow)

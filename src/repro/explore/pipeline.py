@@ -53,7 +53,7 @@ from ..core.mapper import Mapping, map_application
 from ..core.merge import add_pattern, baseline_datapath, is_pe_pattern
 from ..core.mining import MinedSubgraph, mine_frequent_subgraphs
 from ..core.mis import rank_by_mis
-from ..errors import BudgetExceeded
+from ..errors import BudgetExceeded, Unroutable
 from ..graphir.graph import Graph
 from ..obs import event as obs_event, span
 from ..obs.memprof import stage_memory
@@ -166,6 +166,18 @@ def pnr_grouped(items: List[Tuple[str, Any, Mapping, Graph, int]],
     which pairs share its dispatch.  Routing and costing stay per-pair
     (they are cheap Python); only the annealing hot loop is batched.
 
+    Only legal routes are served: the cheapest chain's placement is routed
+    first, and while the router leaves a channel over its track count the
+    next chain's, in cost order.  The first legal routing is the pair's
+    result, at its own chain's cost; when no chain routes, the pair fails
+    with :class:`~repro.errors.Unroutable` (counted as
+    ``pnr.route.unroutable``).  Each served pair observes
+    ``pnr.route.ripups`` (nets rerouted, over its tries) and
+    ``pnr.route.fell_back`` (1 when a chain other than the cheapest was
+    served); its ``pnr.pair`` span carries the served routing's
+    ``iterations``, ``overflow``, and ``ripups``, ``chain_rank`` and
+    ``tries``.
+
     ``isolate=True``: a failing pair (fault-injection site ``pnr``, an
     over-budget anneal, a lowering/routing error) yields the Exception
     object at its index instead of killing the batch.  Content-nonce
@@ -240,19 +252,43 @@ def pnr_grouped(items: List[Tuple[str, Any, Mapping, Graph, int]],
         netlist, spec, prob = lowered[i]
         slots, costs = annealed[i]
         try:
-            best = int(np.argmin(costs))
-            coords: Dict[str, Coord] = {}
-            for idx, name in enumerate(prob.cell_names):
-                x, y = prob.slot_xy[slots[best][prob.entity_of(idx)]]
-                coords[name] = (int(x), int(y))
-            with span("pnr.pair", pe=pe_name, app=mapping.app_name):
-                placement = Placement(coords=coords, cost=float(costs[best]),
-                                      backend="jax", chains=options.chains,
-                                      sweeps=options.sweeps,
-                                      chain_costs=[float(c) for c in costs])
-                routes = route_nets(netlist, placement, spec)
+            with span("pnr.pair", pe=pe_name, app=mapping.app_name) as sp:
+                # cheapest chain first (ties by chain index, as argmin)
+                chain_costs = [float(c) for c in costs]
+                ripups, overflows = 0, []
+                for rank, chain in enumerate(np.argsort(costs,
+                                                        kind="stable")):
+                    coords: Dict[str, Coord] = {}
+                    for idx, name in enumerate(prob.cell_names):
+                        x, y = prob.slot_xy[slots[chain][prob.entity_of(idx)]]
+                        coords[name] = (int(x), int(y))
+                    placement = Placement(
+                        coords=coords, cost=float(costs[chain]),
+                        backend="jax", chains=options.chains,
+                        sweeps=options.sweeps,
+                        chain_costs=chain_costs)
+                    routes = route_nets(netlist, placement, spec)
+                    ripups += routes.ripups
+                    overflows.append(routes.overflow)
+                    if routes.success:
+                        break
+                if sp is not None:
+                    sp.attrs.update(iterations=routes.iterations,
+                                    overflow=routes.overflow, ripups=ripups,
+                                    chain_rank=rank, tries=rank + 1)
+                if not routes.success:
+                    if registry is not None:
+                        registry.inc("pnr.route.unroutable")
+                    raise Unroutable(
+                        f"{pe_name}/{mapping.app_name}: none of "
+                        f"{len(costs)} chains routes on {spec.summary()}; "
+                        f"fewest tracks over capacity {min(overflows)}",
+                        min(overflows))
                 fc = evaluate_fabric(dp, mapping, netlist, placement, routes,
                                      spec, pe_name=pe_name)
+            if registry is not None:
+                registry.observe("pnr.route.ripups", ripups)
+                registry.observe("pnr.route.fell_back", int(rank > 0))
             results.append(PnRResult(spec, netlist, placement, routes, fc))
         except Exception as e:
             if not isolate:
@@ -707,6 +743,12 @@ class Explorer:
                 pnrs = pnr_grouped(items, options, self.stats,
                                    isolate=self._isolating())
                 for (v, a, key), pnr in zip(misses, pnrs):
+                    if isinstance(pnr, Unroutable):
+                        # every chain was routed: a serial retry would
+                        # place from another seed stream, so fail it here
+                        self._record_failure("pnr", pnr, pe=v.name, app=a)
+                        self._failed.add(key)
+                        continue
                     if isinstance(pnr, Exception):
                         # fell out of its batch group: one serial retry,
                         # then a StageFailure row — groupmates unaffected

@@ -20,6 +20,7 @@ from typing import Optional
 
 from ..core.mapper import Mapping
 from ..core.pe import Datapath
+from ..errors import Unroutable
 from ..graphir.graph import Graph
 from .arch import FabricSpec, manhattan
 from .cost import FabricCost, attach_fabric, evaluate_fabric
@@ -63,6 +64,9 @@ def place_and_route(dp: Datapath, mapping: Mapping, app: Graph,
                     pnr_mode: str = "flat") -> PnRResult:
     """Full flow: netlist -> place -> route -> array-level cost.
 
+    Raises :class:`~repro.errors.Unroutable` when the routing of the one
+    placement leaves a channel over its track count.
+
     ``pnr_mode="hierarchical"`` runs :func:`place_hierarchical` (cluster ->
     detail -> deblock) instead of the flat single-level anneal — worth it
     for mega-fabrics, pure overhead for the small arrays single mapped
@@ -89,6 +93,10 @@ def place_and_route(dp: Datapath, mapping: Mapping, app: Graph,
     else:
         raise ValueError(f"unknown pnr_mode {pnr_mode!r}")
     routes = route_nets(netlist, placement, spec)
+    if not routes.success:
+        raise Unroutable(f"{pe_name}/{mapping.app_name}: routing on "
+                         f"{spec.summary()} leaves {routes.overflow} "
+                         f"track(s) over capacity", routes.overflow)
     fc = evaluate_fabric(dp, mapping, netlist, placement, routes, spec,
                          pe_name=pe_name)
     return PnRResult(spec, netlist, placement, routes, fc)

@@ -45,6 +45,7 @@ class RouteResult:
     max_util: float                   # worst edge usage / capacity
     iterations: int
     edge_usage: Dict[Edge, int] = field(default_factory=dict)
+    ripups: int = 0                   # nets ripped up and rerouted
 
     @property
     def success(self) -> bool:
@@ -141,7 +142,7 @@ def route_nets(netlist: Netlist, placement: Placement, spec: FabricSpec,
         work.append((n.name, driver, sinks))
 
     routed: Dict[str, RoutedNet] = {}
-    iters = 0
+    iters = ripups = 0
     pending = list(work)
     pf = pres_fac
     for it in range(max_iters):
@@ -164,6 +165,7 @@ def route_nets(netlist: Netlist, placement: Placement, spec: FabricSpec,
                 pending.append((name, driver, sinks))
         if not pending:
             break
+        ripups += len(pending)
         pf *= 1.6
 
     nets = [routed[name] for name, _, _ in work]
@@ -173,4 +175,5 @@ def route_nets(netlist: Netlist, placement: Placement, spec: FabricSpec,
                        wirelength=sum(n.wirelength for n in nets),
                        overflow=overflow, max_util=max_util,
                        iterations=iters,
-                       edge_usage={e: u for e, u in usage.items() if u})
+                       edge_usage={e: u for e, u in usage.items() if u},
+                       ripups=ripups)
