@@ -7,7 +7,9 @@ store — repeat requests answer from cache in milliseconds without
 touching JAX — and continuously batches the rest: pending (variant,
 app) pairs from *different* requests are grouped by pow2 bucket
 signature and flushed through the batch-first pnr/schedule/simulate
-stages together when a batch fills or the max-wait deadline expires.
+stages together: at once when no batch is executing, else as one batch
+when the running one returns (an opt-in ``max_wait_ms`` linger holds a
+batch that is not full for more same-config requests).
 
 Bit-identity guarantee: a request's records are byte-identical whether
 it is served solo, batched with strangers, or answered from cache —

@@ -179,9 +179,11 @@ def main(argv=None) -> int:
     ap.add_argument("--max-batch-apps", type=int, default=8,
                     help="flush a batch once this many distinct apps "
                          "are pending (default 8)")
-    ap.add_argument("--max-wait-ms", type=float, default=50.0,
-                    help="flush the oldest ticket after this long even "
-                         "if the batch is not full (default 50)")
+    ap.add_argument("--max-wait-ms", type=float, default=0.0,
+                    help="opt-in linger: hold a batch that is not full "
+                         "up to this long for more same-config requests "
+                         "(default 0: flush as soon as nothing is "
+                         "executing)")
     ap.add_argument("--queue-limit", type=int, default=32,
                     help="bounded admission queue: tickets beyond this "
                          "wait at the door (default 32)")

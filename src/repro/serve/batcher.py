@@ -16,11 +16,24 @@ work allows:
   :class:`QueueFull` when ``block=False``;
 * **continuous batching** — pending tickets with the same config are
   merged into one :class:`~repro.explore.Explorer` run over the union
-  of their apps when enough work accumulates (``max_batch_apps``) or
-  the oldest ticket's ``max_wait_s`` deadline expires.  The Explorer's
-  batch-first pnr/schedule/simulate stages then group the merged
-  (variant, app) pairs by pow2 bucket signature, so strangers' pairs
-  share JAX dispatches.
+  of their apps.  The Explorer's batch-first pnr/schedule/simulate
+  stages then group the merged (variant, app) pairs by pow2 bucket
+  signature, so strangers' pairs share JAX dispatches.
+
+The flush policy is work-conserving.  Batches run one at a time, so
+whenever the flush loop looks at the queue nothing is executing: it
+yields one event-loop turn (submits decoded in the same turn join) and
+flushes the oldest ready group at once.  Tickets admitted while a batch
+executes accumulate and flush together when it returns, up to
+``max_batch_apps`` apps — that is where coalescing pays under load.  A
+positive ``max_wait_s`` is an opt-in linger: a group that is not full
+then waits until its oldest ticket has queued that long, for more
+same-config work.  Solo tickets never linger, since nothing can join
+them.  Each batch counts its reason in ``serve.flush.<reason>`` and
+carries it as the ``reason`` attribute of its ``serve.queue`` spans:
+``full`` (``max_batch_apps`` reached), ``idle`` (no linger
+configured), ``solo`` (a solo ticket skipped the linger), ``deadline``
+(the linger expired) or ``drain`` (flushed on close).
 
 The whole scheme is sound because of the pipeline's content-key +
 content-nonce discipline: in ``per_app`` mode every stage artifact of an
@@ -128,7 +141,7 @@ class ContinuousBatcher:
     """
 
     def __init__(self, store: Optional[Dict] = None, *,
-                 max_batch_apps: int = 8, max_wait_s: float = 0.05,
+                 max_batch_apps: int = 8, max_wait_s: float = 0.0,
                  queue_limit: int = 32, cache_limit: int = 256,
                  metrics: Optional[MetricsRegistry] = None) -> None:
         if max_batch_apps < 1:
@@ -240,9 +253,10 @@ class ContinuousBatcher:
                     continue
                 await self._wake.wait()
                 continue
+            await asyncio.sleep(0)            # same-turn submits join
             now = time.perf_counter()
-            batch = self._select_batch(now)
-            if batch is None:
+            picked = self._select_batch(now)
+            if picked is None:
                 oldest = min(t.enqueued for t in self._pending)
                 delay = max(0.0, oldest + self.max_wait_s - now)
                 self._wake.clear()
@@ -251,17 +265,35 @@ class ContinuousBatcher:
                 except asyncio.TimeoutError:
                     pass
                 continue
-            await self._flush(batch, loop)
+            await self._flush(*picked, loop)
 
-    def _select_batch(self, now: float) -> Optional[List[_Ticket]]:
-        """The next batch to flush, or None if nothing is ready yet.
+    def _flush_reason(self, tickets: List[_Ticket], napps: int,
+                      now: float) -> Optional[str]:
+        """Why a config group flushes now, or None while it lingers."""
+        if napps >= self.max_batch_apps:
+            return "full"
+        if self.max_wait_s <= 0:
+            return "idle"
+        if tickets[0].solo:
+            return "solo"
+        if now - tickets[0].enqueued >= self.max_wait_s:
+            return "deadline"
+        return "drain" if self._stopping else None
 
-        A config group is ready when its pending apps reach
-        ``max_batch_apps``, when its oldest ticket has waited
-        ``max_wait_s``, or when the batcher is draining.  Tickets whose
-        app *names* collide with a different graph already in the batch
-        are deferred to a later flush (same name + same graph is fine —
-        that's sharing, the point of batching).
+    def _select_batch(self, now: float
+                      ) -> Optional[Tuple[List[_Ticket], str]]:
+        """The next batch to flush and its reason, or None if nothing is
+        ready yet.
+
+        Called only while no batch executes.  A config group is ready at
+        once unless a linger is configured (``max_wait_s`` > 0); then it
+        is ready when its pending apps reach ``max_batch_apps``, when its
+        oldest ticket has waited ``max_wait_s``, when it is a solo
+        ticket, or when the batcher is draining.  Of the ready groups the
+        one with the oldest ticket flushes.  Tickets whose app *names*
+        collide with a different graph already in the batch are deferred
+        to a later flush (same name + same graph is fine — that's
+        sharing, the point of batching).
         """
         ready: Dict[str, List[_Ticket]] = {}
         napps: Dict[str, int] = {}
@@ -269,12 +301,12 @@ class ContinuousBatcher:
             g = t.key if t.solo else t.group  # solo tickets: own group
             ready.setdefault(g, []).append(t)
             napps[g] = napps.get(g, 0) + len(t.request.apps)
-        pick = None
+        pick, reason = None, None
         for g, tickets in ready.items():
-            if (self._stopping or napps[g] >= self.max_batch_apps
-                    or now - tickets[0].enqueued >= self.max_wait_s):
-                if pick is None or tickets[0].enqueued < pick[0].enqueued:
-                    pick = tickets
+            why = self._flush_reason(tickets, napps[g], now)
+            if why is not None and (
+                    pick is None or tickets[0].enqueued < pick[0].enqueued):
+                pick, reason = tickets, why
         if pick is None:
             return None
 
@@ -288,9 +320,10 @@ class ContinuousBatcher:
                 continue                      # same name, different graph
             batch.append(t)
             apps.update(t.app_keys)
-        return batch or None
+        return batch, reason
 
-    async def _flush(self, batch: List[_Ticket], loop) -> None:
+    async def _flush(self, batch: List[_Ticket], reason: str,
+                     loop) -> None:
         now = time.perf_counter()
         for t in batch:
             self._pending.remove(t)
@@ -299,9 +332,10 @@ class ContinuousBatcher:
             self.metrics.observe("serve.time_in_queue_ms",
                                  (now - t.enqueued) * 1e3)
             record_span("serve.queue", t.enqueued, now, t.parent,
-                        rid=t.request.rid)
+                        rid=t.request.rid, reason=reason)
         self.metrics.set_gauge("serve.queue_depth", self._depth)
         self.metrics.inc("serve.batches")
+        self.metrics.inc(f"serve.flush.{reason}")
         self.metrics.observe("serve.batch_tickets", len(batch))
         napps = len({(n, k) for t in batch for n, k in t.app_keys.items()})
         self.metrics.observe("serve.batch_apps", napps)

@@ -59,7 +59,7 @@ class ExploreService:
     """
 
     def __init__(self, store: Union[None, str, Dict] = None, *,
-                 max_batch_apps: int = 8, max_wait_ms: float = 50.0,
+                 max_batch_apps: int = 8, max_wait_ms: float = 0.0,
                  queue_limit: int = 32,
                  metrics: Optional[MetricsRegistry] = None) -> None:
         self.metrics = metrics or MetricsRegistry()

@@ -201,7 +201,7 @@ def test_served_request_spans_join_by_rid(tracer):
     assert [c.name for c in req.children] == [
         "serve.decode", "serve.queue", "serve.encode"]
     decode, queue, encode = req.children
-    assert queue.attrs == {"rid": "r1"}
+    assert queue.attrs == {"rid": "r1", "reason": "deadline"}
     assert decode.t1 <= queue.t0 and queue.t1 <= encode.t0
     for kid in req.children:
         assert _inside(kid, req) and kid.thread == req.thread

@@ -14,6 +14,7 @@ The serving layer's contract under test:
 
 import asyncio
 import json
+import threading
 
 import pytest
 
@@ -173,6 +174,126 @@ def test_deadline_flush_without_full_batch():
     assert waited >= 0.03                     # sat out most of max_wait
     q = metrics.histogram("serve.time_in_queue_ms")
     assert q.count == 1 and q.vmax >= 30
+
+
+def test_lone_ticket_flushes_without_waiting():
+    conv = _conv()
+
+    async def go():
+        async with ExploreService() as svc:
+            resp = await svc.explore("r1", {"conv": conv}, LIGHT_CFG)
+            return resp, svc.metrics
+
+    resp, metrics = asyncio.run(go())
+    assert resp.ok and resp.records
+    # work-conserving default: nothing executes, so no linger
+    q = metrics.histogram("serve.time_in_queue_ms")
+    assert q.count == 1 and q.vmax < 10
+    assert metrics.counters("serve.flush.") == {"serve.flush.idle": 1}
+    assert metrics.counter("serve.batches") == 1
+
+
+def test_domain_ticket_skips_a_configured_linger():
+    conv, fir = _conv(), _fir()
+    cfg = LIGHT_CFG.replace(mode="domain")
+
+    async def go():
+        async with ExploreService(max_batch_apps=100,
+                                  max_wait_ms=60_000) as svc:
+            resp = await svc.explore("r1", {"conv": conv, "fir": fir}, cfg)
+            return resp, svc.metrics
+
+    resp, metrics = asyncio.run(go())
+    assert resp.ok and resp.records
+    # a solo ticket can share no batch: waiting would only cost it
+    q = metrics.histogram("serve.time_in_queue_ms")
+    assert q.count == 1 and q.vmax < 10
+    assert metrics.counters("serve.flush.") == {"serve.flush.solo": 1}
+
+
+def test_tickets_admitted_during_a_batch_share_the_next():
+    conv, fir, blur = _conv(), _fir(), _blur()
+    later = [("r1", {"fir": fir}), ("r2", {"blur": blur})]
+    solo = {rid: _solo_lines(apps, LIGHT_CFG) for rid, apps in later}
+    started, release = threading.Event(), threading.Event()
+
+    async def go():
+        async with ExploreService() as svc:
+            run_batch = svc.batcher._run_batch
+
+            def held(batch):                  # hold the first batch
+                if not started.is_set():
+                    started.set()
+                    release.wait(60)
+                return run_batch(batch)
+
+            svc.batcher._run_batch = held
+            loop = asyncio.get_running_loop()
+            try:
+                first = asyncio.ensure_future(
+                    svc.explore("r0", {"conv": conv}, LIGHT_CFG))
+                assert await loop.run_in_executor(None, started.wait, 60)
+                rest = [asyncio.ensure_future(
+                    svc.explore(rid, apps, LIGHT_CFG))
+                    for rid, apps in later]
+                while svc.batcher.queue_depth < len(later):
+                    await asyncio.sleep(0)
+            finally:
+                release.set()
+            resps = await asyncio.gather(first, *rest)
+            return resps, svc.metrics
+
+    resps, metrics = asyncio.run(go())
+    assert all(r.ok and r.records for r in resps)
+    for (rid, _apps), resp in zip(later, resps[1:]):
+        assert resp.record_lines() == solo[rid], \
+            f"{rid}: batched records != solo records"
+    assert metrics.counter("serve.batches") == 2
+    assert metrics.histogram("serve.batch_tickets").vmax == len(later)
+    assert metrics.counters("serve.flush.") == {"serve.flush.idle": 2}
+
+
+def test_same_turn_submits_share_a_batch_by_default():
+    conv, fir, blur = _conv(), _fir(), _blur()
+    clients = [("r1", {"conv": conv}), ("r2", {"fir": fir}),
+               ("r3", {"blur": blur})]
+    solo = {rid: _solo_lines(apps, LIGHT_CFG) for rid, apps in clients}
+
+    async def go():
+        async with ExploreService() as svc:
+            resps = await asyncio.gather(*[
+                svc.explore(rid, apps, LIGHT_CFG) for rid, apps in clients])
+            return resps, svc.metrics
+
+    resps, metrics = asyncio.run(go())
+    for (rid, _apps), resp in zip(clients, resps):
+        assert resp.ok and resp.record_lines() == solo[rid]
+    assert metrics.counter("serve.batches") <= 2
+
+
+@pytest.mark.parametrize("reason,service", [
+    ("full", dict(max_batch_apps=1, max_wait_ms=60_000)),
+    ("deadline", dict(max_batch_apps=100, max_wait_ms=20)),
+    ("drain", dict(max_batch_apps=100, max_wait_ms=60_000)),
+])
+def test_flush_reason_counted(reason, service):
+    conv = _conv()
+
+    async def go():
+        async with ExploreService(**service) as svc:
+            t = asyncio.ensure_future(
+                svc.explore("r1", {"conv": conv}, LIGHT_CFG))
+            if reason == "drain":             # admit it, then close
+                while svc.batcher.queue_depth < 1:
+                    await asyncio.sleep(0)
+            else:
+                await t
+        return await t, svc.metrics       # drain: flushed by the close
+
+    resp, metrics = asyncio.run(go())
+    assert resp.ok and resp.records
+    assert metrics.counter("serve.batches") == 1
+    assert metrics.counters("serve.flush.") == {f"serve.flush.{reason}": 1}
 
 
 def test_bounded_queue_backpressure():
