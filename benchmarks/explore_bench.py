@@ -1,11 +1,10 @@
 """Batched-vs-serial stage benchmarks on the Fig. 11 ML suite.
 
-Default mode — the pnr stage: the pre-``repro.explore`` driver placed
-every (variant, app) pair in its own annealing call: one jit compile per
-problem shape plus one device dispatch per pair.  The Explorer's ``pnr``
-stage gathers all pairs, pads them to bucket shapes, and anneals every
-bucket-compatible group's chains in ONE JAX dispatch — so a whole
-exploration pays a couple of compiles instead of one per pair.
+Default mode — the pnr stage: a pair placed alone
+(:func:`repro.fabric.place_and_route`, the deprecated drivers' path)
+costs one device dispatch per pair.  The Explorer's ``pnr`` stage
+gathers all pairs, pads them to bucket shapes, and anneals every
+bucket-compatible group's chains in ONE JAX dispatch.
 
 ``--simulate`` — the schedule/simulate stages: the per-pair loop runs the
 modulo scheduler one pair at a time in Python and compiles one
@@ -136,50 +135,60 @@ def run(out_path: str = DEFAULT_OUT, smoke: bool = False,
     from repro.obs import jaxprof
     jaxprof.enable(registry=base.metrics)
 
-    def timed_pnr(pnr_batch: str):
+    from repro.explore.pipeline import _pair_nonce, _pnr_pair
+    import importlib
+    # repro.fabric re-exports the place() *function*, shadowing the
+    # submodule attribute — resolve the module explicitly
+    place_mod = importlib.import_module("repro.fabric.place")
+
+    def timed_serial():
+        # one dispatch per pair, each placed alone as place_and_route
+        # places it, from fresh annealer programs
+        place_mod._build_batch_annealer.cache_clear()
+        datapaths = {(v.name, a): v.datapath
+                     for a, variants in base.merge().items()
+                     for v in variants}
+        t0 = time.perf_counter()
+        pnrs = {pair: _pnr_pair(pair[0], datapaths[pair], mapping,
+                                apps[pair[1]], fabric, _pair_nonce(*pair))
+                for pair, mapping in base.map().items()}
+        return time.perf_counter() - t0, pnrs, len(pnrs)
+
+    def timed_grouped():
         # fresh annealer programs + a purged pnr memo per repeat (cold
-        # caches emulate a fresh exploration); the memo store is shared
-        # for the upstream stages but pnr keys include pnr_batch, so each
-        # mode places from scratch
-        import importlib
-        # repro.fabric re-exports the place() *function*, shadowing the
-        # submodule attribute — resolve the module explicitly
-        place_mod = importlib.import_module("repro.fabric.place")
-        place_mod._build_annealer.cache_clear()
+        # caches emulate a fresh exploration)
         place_mod._build_batch_annealer.cache_clear()
         base.forget("pnr")
-        ex = base.with_config(pnr_batch=pnr_batch)
-        before = ex.stats["pnr_dispatch"]     # the stats Counter is shared
+        before = base.stats["pnr_dispatch"]
         t0 = time.perf_counter()
-        pnrs = ex.pnr()
+        pnrs = base.pnr()
         dt = time.perf_counter() - t0
-        failures.extend(ex.failures)          # clean-run proof: see the
-        return dt, pnrs, ex.stats["pnr_dispatch"] - before   # failures block
+        failures.extend(base.failures)        # clean-run proof: see the
+        return dt, pnrs, base.stats["pnr_dispatch"] - before  # failures
 
     samples = {"serial_s": [], "grouped_s": []}
     failures: list = []
     serial_pnrs = serial_disp = None
     for _ in range(repeats):
-        dt, serial_pnrs, serial_disp = timed_pnr("serial")
+        dt, serial_pnrs, serial_disp = timed_serial()
         samples["serial_s"].append(dt)
     grouped_pnrs = grouped_disp = None
     before = None
     for _ in range(repeats):
         before = _counter_snapshot(base.metrics)   # last repeat's deltas
-        dt, grouped_pnrs, grouped_disp = timed_pnr("grouped")
+        dt, grouped_pnrs, grouped_disp = timed_grouped()
         samples["grouped_s"].append(dt)
     metrics = _metrics_block(base.metrics, before,
                              ("pnr_dispatch", "memo_miss", "memo_hit",
                               "compile_events"))
-    _memory_pass(base, ("pnr",),
-                 lambda: base.with_config(pnr_batch="grouped").pnr())
+    _memory_pass(base, ("pnr",), base.pnr)
     metrics.update(_memory_gauges(base.metrics, ("pnr",)))
     jaxprof.disable()
 
     pairs = len(serial_pnrs)
     assert len(grouped_pnrs) == pairs
     # both modes must produce equally valid arrays: every net routed on a
-    # legally fitted grid
+    # legally fitted grid (place_and_route refuses anything else)
     for pnrs in (serial_pnrs, grouped_pnrs):
         for pnr in pnrs.values():
             assert pnr.routes.success, "routing overflow in benchmark run"

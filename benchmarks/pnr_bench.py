@@ -41,7 +41,7 @@ from repro.core import baseline_datapath, map_application
 from repro.core.dse import app_ops
 from repro.fabric import (FabricSpec, extract_netlist, lower, place,
                           place_hierarchical, route_nets, synthetic_netlist)
-from repro.fabric.place import anneal_jax, anneal_python
+from repro.fabric.place import anneal_jax_batch, anneal_python
 
 from .common import emit, manifest_block, repeats_block
 
@@ -63,6 +63,12 @@ HIER_FLAT_MAX = 128
 HIER_LOCALITY = 4
 
 
+def _anneal(problem, **kw):
+    """(slots, costs) of one problem's chains, annealed as a group of one."""
+    (slots, costs), = anneal_jax_batch([problem], **kw)
+    return slots, costs
+
+
 def _timed_anneal(problem, score_mode: str, *, chains: int, sweeps: int,
                   seed: int, repeats: int = 1):
     """(wall-second samples, slots, costs) for steady-state annealer calls.
@@ -71,14 +77,14 @@ def _timed_anneal(problem, score_mode: str, *, chains: int, sweeps: int,
     the samples measure dispatch+run noise while slots/costs stay
     bit-identical across repeats.
     """
-    anneal_jax(problem, chains=chains, seed=seed, sweeps=sweeps,
-               score_mode=score_mode)                   # trace + compile
+    _anneal(problem, chains=chains, seed=seed, sweeps=sweeps,
+            score_mode=score_mode)                      # trace + compile
     samples = []
     slots = costs = None
     for _ in range(max(1, repeats)):
         t0 = time.perf_counter()
-        slots, costs = anneal_jax(problem, chains=chains, seed=seed + 1,
-                                  sweeps=sweeps, score_mode=score_mode)
+        slots, costs = _anneal(problem, chains=chains, seed=seed + 1,
+                               sweeps=sweeps, score_mode=score_mode)
         samples.append(time.perf_counter() - t0)
     return samples, slots, costs
 
@@ -137,15 +143,15 @@ def anneal_64x64(*, chains: int = 2, sweeps: int = 8, seed: int = 4,
     spec = FabricSpec(rows=64, cols=64)
     problem = lower(synthetic_netlist(spec, seed=seed), spec)
     t0 = time.perf_counter()
-    anneal_jax(problem, chains=chains, seed=seed, sweeps=sweeps,
-               score_mode="delta")                      # trace + compile
+    _anneal(problem, chains=chains, seed=seed, sweeps=sweeps,
+            score_mode="delta")                         # trace + compile
     compile_s = time.perf_counter() - t0
     samples = []
     costs = None
     for _ in range(max(1, repeats)):
         t0 = time.perf_counter()
-        _, costs = anneal_jax(problem, chains=chains, seed=seed + 1,
-                              sweeps=sweeps, score_mode="delta")
+        _, costs = _anneal(problem, chains=chains, seed=seed + 1,
+                           sweeps=sweeps, score_mode="delta")
         samples.append(time.perf_counter() - t0)
     wall = statistics.median(samples)
     rec = {"rows": 64, "cols": 64, "chains": chains, "sweeps": sweeps,
@@ -283,9 +289,9 @@ def harris_bench() -> dict:
     # -- jax batched chains (first call includes trace+compile; report the
     # steady-state second call, which is what a DSE sweep pays) ------------
     for chains in CHAIN_COUNTS:
-        anneal_jax(problem, chains=chains, seed=0, sweeps=SWEEPS)  # warmup
+        _anneal(problem, chains=chains, seed=0, sweeps=SWEEPS)  # warmup
         t0 = time.perf_counter()
-        _, costs = anneal_jax(problem, chains=chains, seed=1, sweeps=SWEEPS)
+        _, costs = _anneal(problem, chains=chains, seed=1, sweeps=SWEEPS)
         dt = (time.perf_counter() - t0) * 1e6
         out["jax_us_per_chain"][chains] = dt / chains
         emit(f"pnr_anneal_jax_c{chains}", dt / chains,

@@ -161,17 +161,24 @@ def build_variants(app_name: str, app: Graph,
     return variants
 
 
-def _explorer_config(mode: str, mining: Optional[MiningConfig],
-                     options: Optional["FabricOptions"], **kw):
-    """Build the ExploreConfig a legacy driver call corresponds to.
-
-    ``pnr_batch="serial"`` pins the one-dispatch-per-pair annealing loop,
-    which is what makes the shims reproduce the pre-``repro.explore``
-    records bit-identically at equal seeds.
+def _explore(mode: str, apps: Dict[str, Graph],
+             mining: Optional[MiningConfig],
+             options: Optional["FabricOptions"],
+             **kw) -> Dict[str, DSEResult]:
+    """Run a legacy driver call: an :class:`repro.explore.Explorer` mines,
+    ranks, merges and maps; with a fabric, :func:`repro.explore.
+    evaluate_pairs` then places, routes and simulates every pair, as
+    :func:`evaluate_variants` does, so the shims and ``evaluate_variants``
+    give the same records for the same variants.
     """
     from ..explore.config import ExploreConfig
-    return ExploreConfig(mode=mode, mining=mining or MiningConfig(),
-                         fabric=options, pnr_batch="serial", **kw)
+    from ..explore.pipeline import Explorer, evaluate_pairs
+    results = Explorer(apps, ExploreConfig(
+        mode=mode, mining=mining or MiningConfig(), **kw)).run().results
+    if options is not None:
+        for res in results.values():
+            evaluate_pairs(res.variants, res.apps, options)
+    return results
 
 
 def evaluate_variants(variants: Sequence[PEVariant],
@@ -185,8 +192,8 @@ def evaluate_variants(variants: Sequence[PEVariant],
                       simulate: bool = False) -> None:
     """Deprecated shim: map + cost every (variant, app) pair in place.
 
-    Delegates to :func:`repro.explore.evaluate_pairs` (serial mode — the
-    legacy loop, bit-identical at equal seeds).  The loose ``fabric_*``
+    Delegates to :func:`repro.explore.evaluate_pairs` (one pair at a
+    time, each pair's chains keyed by its name).  The loose ``fabric_*
     kwargs emit :class:`DeprecationWarning`; new code should run an
     :class:`repro.explore.Explorer` (which also batches the annealing
     across pairs) or pass a full :class:`repro.fabric.FabricOptions`.
@@ -210,7 +217,7 @@ def evaluate_variants(variants: Sequence[PEVariant],
                                    chains=fabric_chains,
                                    sweeps=fabric_sweeps, seed=fabric_seed,
                                    simulate=simulate)
-    evaluate_pairs(variants, apps, options, pnr_batch="serial")
+    evaluate_pairs(variants, apps, options)
 
 
 def specialize_per_app(apps: Dict[str, Graph],
@@ -227,22 +234,20 @@ def specialize_per_app(apps: Dict[str, Graph],
                        simulate: bool = False) -> Dict[str, DSEResult]:
     """Deprecated shim: per-application DSE (paper Sec. V-A camera sweep).
 
-    Runs an :class:`repro.explore.Explorer` in ``per_app`` mode with
-    ``pnr_batch="serial"``, reproducing the pre-redesign records
-    bit-identically at equal seeds.  New code should build an
-    :class:`repro.explore.ExploreConfig` directly — it memoizes every
-    stage and batches the annealing across (variant, app) pairs.
+    Runs an :class:`repro.explore.Explorer` in ``per_app`` mode and
+    evaluates its variants as :func:`evaluate_variants` does.  New code
+    should build an :class:`repro.explore.ExploreConfig` directly — it
+    memoizes every stage and batches the annealing across (variant, app)
+    pairs.
     """
-    from ..explore.pipeline import Explorer
     from ..fabric.options import FabricOptions
 
     options = FabricOptions.coerce(fabric, backend=fabric_backend,
                                    chains=fabric_chains,
                                    sweeps=fabric_sweeps, seed=fabric_seed,
                                    simulate=simulate)
-    cfg = _explorer_config("per_app", mining, options, max_merge=max_merge,
-                           rank_mode=rank_mode, validate=validate)
-    return Explorer(apps, cfg).run().results
+    return _explore("per_app", apps, mining, options, max_merge=max_merge,
+                    rank_mode=rank_mode, validate=validate)
 
 
 def domain_pe(apps: Dict[str, Graph],
@@ -259,18 +264,16 @@ def domain_pe(apps: Dict[str, Graph],
               simulate: bool = False) -> DSEResult:
     """Deprecated shim: cross-application PE (paper's PE IP / PE ML).
 
-    Runs an :class:`repro.explore.Explorer` in ``domain`` mode with
-    ``pnr_batch="serial"`` — bit-identical to the pre-redesign driver at
-    equal seeds.  New code should use :class:`repro.explore.ExploreConfig`.
+    Runs an :class:`repro.explore.Explorer` in ``domain`` mode and
+    evaluates its variant as :func:`evaluate_variants` does.  New code
+    should use :class:`repro.explore.ExploreConfig`.
     """
-    from ..explore.pipeline import Explorer
     from ..fabric.options import FabricOptions
 
     options = FabricOptions.coerce(fabric, backend=fabric_backend,
                                    chains=fabric_chains,
                                    sweeps=fabric_sweeps, seed=fabric_seed,
                                    simulate=simulate)
-    cfg = _explorer_config("domain", mining, options,
-                           per_app_subgraphs=per_app_subgraphs,
-                           domain_name=domain_name, validate=validate)
-    return Explorer(apps, cfg).run().results[domain_name]
+    return _explore("domain", apps, mining, options,
+                    per_app_subgraphs=per_app_subgraphs,
+                    domain_name=domain_name, validate=validate)[domain_name]

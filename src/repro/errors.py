@@ -13,8 +13,8 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
-__all__ = ["BudgetExceeded", "InjectedFault", "StoreCorruption",
-           "Unroutable"]
+__all__ = ["BudgetExceeded", "ConfigFormatError", "InjectedFault",
+           "StoreCorruption", "Unroutable"]
 
 
 class BudgetExceeded(RuntimeError):
@@ -29,6 +29,12 @@ class BudgetExceeded(RuntimeError):
     def __init__(self, message: str, **budget: Any) -> None:
         super().__init__(message)
         self.budget: Dict[str, Any] = dict(budget)
+
+
+class ConfigFormatError(ValueError):
+    """A config blob (:class:`repro.explore.ExploreConfig`,
+    :class:`repro.fabric.FabricOptions`) that can't be parsed — reported
+    as a one-line error by the CLI, never a stack trace."""
 
 
 class InjectedFault(RuntimeError):

@@ -73,9 +73,8 @@ def _config_from_args(args, mode: str) -> ExploreConfig:
     return ExploreConfig(mode=mode, mining=mining, max_merge=args.max_merge,
                          rank_mode=args.rank_mode, fabric=fabric,
                          per_app_subgraphs=args.per_app_subgraphs,
-                         domain_name=args.name, pnr_batch=args.pnr_batch,
-                         pnr_mode=args.pnr_mode, sim_batch=args.sim_batch,
-                         on_error=args.on_error)
+                         domain_name=args.name, pnr_mode=args.pnr_mode,
+                         sim_batch=args.sim_batch, on_error=args.on_error)
 
 
 def _add_common(sp: argparse.ArgumentParser) -> None:
@@ -101,8 +100,6 @@ def _add_common(sp: argparse.ArgumentParser) -> None:
     sp.add_argument("--chains", type=int, default=8)
     sp.add_argument("--sweeps", type=int, default=16)
     sp.add_argument("--seed", type=int, default=0)
-    sp.add_argument("--pnr-batch", default="grouped",
-                    choices=("grouped", "serial"))
     sp.add_argument("--pnr-mode", default="flat",
                     choices=("flat", "hierarchical"),
                     help="flat: single-level anneal (default); "
@@ -203,7 +200,7 @@ def _run(args, mode: str) -> int:
         res.to_jsonl(args.out)
         print(f"{len(rows)} records -> {args.out}")
     print(f"# {len(rows)} (variant, app) records in {res.elapsed_s:.1f}s "
-          f"[mode={mode}, pnr_batch={cfg.pnr_batch}]")
+          f"[mode={mode}]")
     if res.failures:
         print(f"# DEGRADED: {summarize_failures(res.failures)}",
               file=sys.stderr)

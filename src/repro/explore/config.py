@@ -21,6 +21,7 @@ from dataclasses import asdict, dataclass, field, fields, replace
 from typing import Any, Dict, Optional
 
 from ..core.mining import MiningConfig
+from ..errors import ConfigFormatError
 from ..fabric.options import FabricOptions
 
 #: bump when a field is added/renamed/retyped; from_dict rejects unknown
@@ -31,15 +32,9 @@ from ..fabric.options import FabricOptions
 CONFIG_SCHEMA = 4
 
 MODES = ("per_app", "domain")
-PNR_BATCH_MODES = ("grouped", "serial")
 PNR_MODES = ("flat", "hierarchical")
 SIM_BATCH_MODES = ("grouped", "serial")
 ON_ERROR_MODES = ("isolate", "raise")
-
-
-class ConfigFormatError(ValueError):
-    """An ExploreConfig blob that can't be parsed — reported as a
-    one-line error by the CLI, never a stack trace."""
 
 
 @dataclass(frozen=True)
@@ -58,20 +53,17 @@ class ExploreConfig:
     fabric            — array-level evaluation (place-and-route and, with
                         ``fabric.simulate``, modulo scheduling + cycle-
                         accurate simulation); None = per-tile model only.
-    pnr_batch         — "grouped": all (variant, app) placements of one
-                        bucket signature anneal in one JAX dispatch
-                        (:func:`repro.fabric.place.anneal_jax_batch`);
-                        "serial": one dispatch per pair (the legacy loop —
-                        bit-identical to the pre-``repro.explore`` driver).
     pnr_mode          — "flat": single-level anneal over the whole array
-                        (the default; bit-identical to every build before
-                        this field existed); "hierarchical": two-level
-                        cluster -> detail -> deblock flow
+                        (the default); with the "jax" backend all
+                        (variant, app) placements of one bucket signature
+                        anneal in one JAX dispatch
+                        (:func:`repro.fabric.place.anneal_jax_batch`).
+                        "hierarchical": two-level cluster -> detail ->
+                        deblock flow
                         (:func:`repro.fabric.place.place_hierarchical`)
-                        for mega-fabrics.  Hierarchical pairs run on the
-                        serial dispatch path (each placement is already
-                        internally batched across its clusters), so
-                        ``pnr_batch="grouped"`` is ignored for them.
+                        for mega-fabrics, one pair at a time (each
+                        placement is already internally batched across
+                        its clusters).
     sim_batch         — "grouped": modulo scheduling runs its slot-conflict
                         scans in lockstep across pairs sharing a fabric
                         signature, and all simulations of one bucket
@@ -99,7 +91,6 @@ class ExploreConfig:
     per_app_subgraphs: int = 2
     domain_name: str = "PE_DOM"
     fabric: Optional[FabricOptions] = None
-    pnr_batch: str = "grouped"
     pnr_mode: str = "flat"
     sim_batch: str = "grouped"
     on_error: str = "isolate"
@@ -113,9 +104,6 @@ class ExploreConfig:
                              f"got {self.on_error!r}")
         if self.mode not in MODES:
             raise ValueError(f"mode must be one of {MODES}, got {self.mode!r}")
-        if self.pnr_batch not in PNR_BATCH_MODES:
-            raise ValueError(f"pnr_batch must be one of {PNR_BATCH_MODES}, "
-                             f"got {self.pnr_batch!r}")
         if self.sim_batch not in SIM_BATCH_MODES:
             raise ValueError(f"sim_batch must be one of {SIM_BATCH_MODES}, "
                              f"got {self.sim_batch!r}")
@@ -152,6 +140,13 @@ class ExploreConfig:
                 f"ExploreConfig schema {schema!r} not supported (this build "
                 f"reads schema {CONFIG_SCHEMA}) — regenerate the blob with "
                 f"ExploreConfig.to_dict() from a matching build")
+        # a removed field: blobs that carry it still parse at the one
+        # value the remaining pnr stage computes
+        batching = d.pop("pnr_batch", "grouped")
+        if batching != "grouped":
+            raise ConfigFormatError(
+                f"ExploreConfig field 'pnr_batch' was removed: only "
+                f"'grouped' is still read, got {batching!r}")
         known = {f.name for f in fields(ExploreConfig)}
         unknown = set(d) - known
         if unknown:
@@ -161,7 +156,7 @@ class ExploreConfig:
         for name, want in (("mode", str), ("max_merge", int),
                            ("rank_mode", str), ("validate", bool),
                            ("per_app_subgraphs", int), ("domain_name", str),
-                           ("pnr_batch", str), ("pnr_mode", str),
+                           ("pnr_mode", str),
                            ("sim_batch", str), ("on_error", str)):
             if name in d and (not isinstance(d[name], want)
                               or (want is int and isinstance(d[name], bool))):

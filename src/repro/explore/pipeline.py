@@ -17,10 +17,9 @@ upstream artifact instead of re-mining and re-merging:
 The ``pnr`` stage is batch-first: all (variant, app) mappings are
 gathered, lowered, grouped by :func:`repro.fabric.place.batch_signature`,
 and annealed with chains spread across pairs in one JAX dispatch per
-group (``pnr_batch="grouped"``).  ``pnr_batch="serial"`` runs the legacy
-one-dispatch-per-pair loop and is bit-identical to the pre-``repro.
-explore`` driver — it is what the deprecated ``specialize_per_app`` /
-``domain_pe`` / ``evaluate_variants`` shims pin.
+group.  Each pair's chains are keyed by a content nonce, so a pair placed
+alone (:func:`repro.fabric.place_and_route`, the fault-isolation retry)
+gets the placement, routing and cost its group would have given it.
 
 The ``schedule`` and ``simulate`` stages are batch-first the same way
 (``sim_batch="grouped"``): modulo scheduling advances all pairs of one
@@ -107,15 +106,18 @@ def _mining_fields(cfg: ExploreConfig) -> Tuple:
             m.allow_macros)
 
 
-def _pnr_fields(options: "FabricOptions", pnr_batch: str,
-                pnr_mode: str = "flat") -> Tuple:
+def _pnr_fields(options: "FabricOptions", pnr_mode: str = "flat") -> Tuple:
     s = options.spec
     spec_sig = None if s is None else (s.rows, s.cols, s.channel_width,
                                        s.io_capacity, s.hop_energy_pj,
                                        s.hop_delay_ns, s.latch_depth)
-    sig = (spec_sig, options.backend, options.hpwl_backend,
-           options.score_mode, options.chains, options.sweeps,
-           options.seed, pnr_batch, options.anneal_max_states)
+    # "jnp" and "grouped" hold the places of two removed options at the
+    # values every key now stands for: the key's bytes, and with them the
+    # content nonce that seeds each pair's chains (Explorer.pnr), stay the
+    # ones earlier builds derived, so placements do not move
+    sig = (spec_sig, options.backend, "jnp", options.score_mode,
+           options.chains, options.sweeps, options.seed, "grouped",
+           options.anneal_max_states)
     # flat keys keep their pre-pnr_mode shape so existing memo stores
     # stay warm across the upgrade; hierarchical results key separately
     if pnr_mode != "flat":
@@ -132,6 +134,13 @@ def _sim_fields(options: "FabricOptions") -> Tuple:
             options.sim_verify, options.seed, options.sim_max_cycles)
 
 
+def _key_nonce(key: Tuple) -> int:
+    """Content nonce of one pair's pnr memo key: keys the pair's annealing
+    chains, so its placement never depends on which pairs share its
+    dispatch, or on whether it is placed alone."""
+    return zlib.crc32(repr(key).encode())
+
+
 def _pair_nonce(pe_name: str, app_name: str) -> int:
     """Content nonce for one (variant, app) pair: seeds the pair's golden
     test vectors so simulated results never depend on bucket grouping."""
@@ -141,17 +150,16 @@ def _pair_nonce(pe_name: str, app_name: str) -> int:
 # ---------------------------------------------------------------------------
 # per-pair primitives (shared by the Explorer stages and the legacy shims)
 # ---------------------------------------------------------------------------
-def _pnr_pair(pe_name, dp, mapping, app, options,
-              pnr_mode: str = "flat") -> "PnRResult":
+def _pnr_pair(pe_name, dp, mapping, app, options, nonce: int,
+              pnr_mode: str = "flat", metrics=None) -> "PnRResult":
     from ..fabric import place_and_route
     return place_and_route(dp, mapping, app, options.spec,
                            backend=options.backend, chains=options.chains,
                            sweeps=options.sweeps, seed=options.seed,
-                           pe_name=pe_name,
-                           hpwl_backend=options.hpwl_backend,
+                           nonce=nonce, pe_name=pe_name,
                            score_mode=options.score_mode,
                            max_states=options.anneal_max_states,
-                           pnr_mode=pnr_mode)
+                           pnr_mode=pnr_mode, metrics=metrics)
 
 
 def pnr_grouped(items: List[Tuple[str, Any, Mapping, Graph, int]],
@@ -166,17 +174,13 @@ def pnr_grouped(items: List[Tuple[str, Any, Mapping, Graph, int]],
     which pairs share its dispatch.  Routing and costing stay per-pair
     (they are cheap Python); only the annealing hot loop is batched.
 
-    Only legal routes are served: the cheapest chain's placement is routed
-    first, and while the router leaves a channel over its track count the
-    next chain's, in cost order.  The first legal routing is the pair's
-    result, at its own chain's cost; when no chain routes, the pair fails
-    with :class:`~repro.errors.Unroutable` (counted as
-    ``pnr.route.unroutable``).  Each served pair observes
-    ``pnr.route.ripups`` (nets rerouted, over its tries) and
-    ``pnr.route.fell_back`` (1 when a chain other than the cheapest was
-    served); its ``pnr.pair`` span carries the served routing's
-    ``iterations``, ``overflow``, and ``ripups``, ``chain_rank`` and
-    ``tries``.
+    Only legal routes are served: each pair's chains are routed cheapest
+    first and the first legal routing is its result, at its own chain's
+    cost (:func:`repro.fabric.route.route_first_legal`, the rule
+    :func:`repro.fabric.place_and_route` applies to a pair placed alone);
+    when no chain routes, the pair fails with
+    :class:`~repro.errors.Unroutable`.  The router's observations land in
+    ``stats.registry`` and on the pair's ``pnr.pair`` span.
 
     ``isolate=True``: a failing pair (fault-injection site ``pnr``, an
     over-budget anneal, a lowering/routing error) yields the Exception
@@ -185,13 +189,12 @@ def pnr_grouped(items: List[Tuple[str, Any, Mapping, Graph, int]],
     the failed pair reshapes its dispatch group.
     """
     from ..fabric import PnRResult
-    from ..fabric.arch import Coord, FabricSpec
+    from ..fabric.arch import FabricSpec
     from ..fabric.cost import evaluate_fabric
     from ..fabric.netlist import extract_netlist
-    from ..fabric.place import (Placement, anneal_jax_batch,
-                                batch_signature, check_anneal_budget, lower)
-    from ..fabric.route import route_nets
-    import numpy as np
+    from ..fabric.place import (anneal_jax_batch, batch_signature,
+                                chain_placements, check_anneal_budget, lower)
+    from ..fabric.route import route_first_legal
 
     registry = getattr(stats, "registry", None)
     spec0 = options.spec or FabricSpec()
@@ -220,7 +223,7 @@ def pnr_grouped(items: List[Tuple[str, Any, Mapping, Graph, int]],
         if low is not None:
             groups[batch_signature(low[2], options.sweeps)].append(i)
 
-    annealed: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
+    annealed: Dict[int, Tuple] = {}
     for sig, idxs in groups.items():
         try:
             with span("pnr.dispatch", bucket="x".join(map(str, sig)),
@@ -252,43 +255,15 @@ def pnr_grouped(items: List[Tuple[str, Any, Mapping, Graph, int]],
         netlist, spec, prob = lowered[i]
         slots, costs = annealed[i]
         try:
-            with span("pnr.pair", pe=pe_name, app=mapping.app_name) as sp:
-                # cheapest chain first (ties by chain index, as argmin)
-                chain_costs = [float(c) for c in costs]
-                ripups, overflows = 0, []
-                for rank, chain in enumerate(np.argsort(costs,
-                                                        kind="stable")):
-                    coords: Dict[str, Coord] = {}
-                    for idx, name in enumerate(prob.cell_names):
-                        x, y = prob.slot_xy[slots[chain][prob.entity_of(idx)]]
-                        coords[name] = (int(x), int(y))
-                    placement = Placement(
-                        coords=coords, cost=float(costs[chain]),
-                        backend="jax", chains=options.chains,
-                        sweeps=options.sweeps,
-                        chain_costs=chain_costs)
-                    routes = route_nets(netlist, placement, spec)
-                    ripups += routes.ripups
-                    overflows.append(routes.overflow)
-                    if routes.success:
-                        break
-                if sp is not None:
-                    sp.attrs.update(iterations=routes.iterations,
-                                    overflow=routes.overflow, ripups=ripups,
-                                    chain_rank=rank, tries=rank + 1)
-                if not routes.success:
-                    if registry is not None:
-                        registry.inc("pnr.route.unroutable")
-                    raise Unroutable(
-                        f"{pe_name}/{mapping.app_name}: none of "
-                        f"{len(costs)} chains routes on {spec.summary()}; "
-                        f"fewest tracks over capacity {min(overflows)}",
-                        min(overflows))
+            with span("pnr.pair", pe=pe_name, app=mapping.app_name):
+                placement, routes = route_first_legal(
+                    netlist, chain_placements(prob, slots, costs,
+                                              backend="jax",
+                                              sweeps=options.sweeps),
+                    spec, label=f"{pe_name}/{mapping.app_name}",
+                    metrics=registry)
                 fc = evaluate_fabric(dp, mapping, netlist, placement, routes,
                                      spec, pe_name=pe_name)
-            if registry is not None:
-                registry.observe("pnr.route.ripups", ripups)
-                registry.observe("pnr.route.fell_back", int(rank > 0))
             results.append(PnRResult(spec, netlist, placement, routes, fc))
         except Exception as e:
             if not isolate:
@@ -332,12 +307,12 @@ def _sim_pair(dp, mapping, app, pnr, options, nonce: int) -> Tuple[Any, int]:
 
 
 def evaluate_pairs(variants, apps: Dict[str, Graph],
-                   options: Optional["FabricOptions"], *,
-                   pnr_batch: str = "serial") -> None:
+                   options: Optional["FabricOptions"]) -> None:
     """Map + cost every (variant, app) pair in place; optional array-level
-    PnR and time-domain simulation.  This is the engine behind the
-    deprecated :func:`repro.core.dse.evaluate_variants` shim; the serial
-    mode reproduces the legacy loop bit-for-bit.
+    PnR and time-domain simulation, one pair at a time, each pair's
+    chains keyed by its name (:func:`_pair_nonce`).  This is the engine
+    behind the deprecated :func:`repro.core.dse.evaluate_variants` shim
+    and the array-level half of the other :mod:`repro.core.dse` shims.
     """
     from ..fabric.cost import attach_fabric
 
@@ -352,14 +327,9 @@ def evaluate_pairs(variants, apps: Dict[str, Graph],
     if options is None:
         return
 
-    if pnr_batch == "grouped":
-        items = [(v.name, v.datapath, mapping, app,
-                  _pair_nonce(v.name, app_name))
-                 for v, app_name, app, mapping, _ in todo]
-        pnrs = pnr_grouped(items, options)
-    else:
-        pnrs = [_pnr_pair(v.name, v.datapath, mapping, app, options)
-                for v, app_name, app, mapping, _ in todo]
+    pnrs = [_pnr_pair(v.name, v.datapath, mapping, app, options,
+                      _pair_nonce(v.name, app_name))
+            for v, app_name, app, mapping, _ in todo]
 
     for (v, app_name, app, mapping, cost), pnr in zip(todo, pnrs):
         v.fabric_costs[app_name] = pnr.cost
@@ -704,17 +674,20 @@ class Explorer:
 
         Gathers every pair missing from the memo, lowers all netlists,
         groups them by bucket signature, and anneals each group's chains
-        in one JAX dispatch (``pnr_batch="grouped"``).  Non-"jax" backends,
-        ``pnr_batch="serial"`` and ``pnr_mode="hierarchical"`` fall back
-        to the per-pair loop (a hierarchical placement is itself a batched
-        dispatch across its clusters, so cross-pair grouping buys nothing).
+        in one JAX dispatch (:func:`pnr_grouped`).  The "python" backend
+        and ``pnr_mode="hierarchical"`` run the per-pair loop (a
+        hierarchical placement is itself a batched dispatch across its
+        clusters, so cross-pair grouping buys nothing).  Either way a
+        pair's chains are keyed by the content nonce of its memo key, so
+        a pair that falls out of its group and is retried alone
+        (:func:`_pnr_pair`) gets the result its group would have given.
         """
         cfg = self.config
         options = cfg.fabric
         if options is None:
             raise ValueError("pnr stage requires config.fabric")
         mappings = self.map()
-        sig = _pnr_fields(options, cfg.pnr_batch, cfg.pnr_mode)
+        sig = _pnr_fields(options, cfg.pnr_mode)
 
         keys: Dict[Pair, Tuple] = {}
         misses = []
@@ -731,31 +704,32 @@ class Explorer:
             else:
                 self.metrics.inc("memo.hit.pnr")
 
-        grouped = (cfg.pnr_batch == "grouped" and options.backend == "jax"
-                   and options.hpwl_backend == "jnp"
-                   and cfg.pnr_mode == "flat")
+        def alone(v, a, key):
+            return _pnr_pair(v.name, v.datapath, mappings[(v.name, a)],
+                             self.apps[a], options, _key_nonce(key),
+                             cfg.pnr_mode, self.metrics)
+
+        grouped = options.backend == "jax" and cfg.pnr_mode == "flat"
         with span("pnr", pairs=len(keys), misses=len(misses)), \
                 stage_memory(self.metrics, "pnr"):
             if misses and grouped:
                 items = [(v.name, v.datapath, mappings[(v.name, a)],
-                          self.apps[a], zlib.crc32(repr(key).encode()))
+                          self.apps[a], _key_nonce(key))
                          for v, a, key in misses]
                 pnrs = pnr_grouped(items, options, self.stats,
                                    isolate=self._isolating())
                 for (v, a, key), pnr in zip(misses, pnrs):
                     if isinstance(pnr, Unroutable):
-                        # every chain was routed: a serial retry would
-                        # place from another seed stream, so fail it here
+                        # every chain was routed: a retry would route the
+                        # same chains, so fail it here
                         self._record_failure("pnr", pnr, pe=v.name, app=a)
                         self._failed.add(key)
                         continue
                     if isinstance(pnr, Exception):
-                        # fell out of its batch group: one serial retry,
+                        # fell out of its batch group: one retry alone,
                         # then a StageFailure row — groupmates unaffected
                         pnr = self._retry(
-                            "pnr", lambda v=v, a=a: _pnr_pair(
-                                v.name, v.datapath, mappings[(v.name, a)],
-                                self.apps[a], options, cfg.pnr_mode),
+                            "pnr", lambda v=v, a=a, k=key: alone(v, a, k),
                             pe=v.name, app=a)
                         if pnr is _FAILED:
                             self._failed.add(key)
@@ -767,9 +741,7 @@ class Explorer:
                 for v, a, key in misses:
                     with span("pnr.pair", pe=v.name, app=a):
                         pnr = self._attempt(
-                            "pnr", lambda v=v, a=a: _pnr_pair(
-                                v.name, v.datapath, mappings[(v.name, a)],
-                                self.apps[a], options, cfg.pnr_mode),
+                            "pnr", lambda v=v, a=a, k=key: alone(v, a, k),
                             pe=v.name, app=a)
                     if pnr is _FAILED:
                         self._failed.add(key)
@@ -796,7 +768,7 @@ class Explorer:
             raise ValueError("schedule stage requires config.fabric")
         mappings = self.map()
         pnrs = self.pnr()
-        sig = _pnr_fields(options, cfg.pnr_batch, cfg.pnr_mode)
+        sig = _pnr_fields(options, cfg.pnr_mode)
 
         def serial_sched(v, a):
             return build_sim(v.datapath, mappings[(v.name, a)],
@@ -883,7 +855,7 @@ class Explorer:
             if pair not in progs:                    # failed upstream
                 continue
             key = ("sim", map_key[1:],
-                   _pnr_fields(options, cfg.pnr_batch, cfg.pnr_mode),
+                   _pnr_fields(options, cfg.pnr_mode),
                    _sim_fields(options), cfg.sim_batch,
                    _sched_fields(options))
             if key in self._failed:          # degraded earlier this run

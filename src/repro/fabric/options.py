@@ -13,6 +13,7 @@ import warnings
 from dataclasses import asdict, dataclass, fields, replace
 from typing import Any, Dict, Optional
 
+from ..errors import ConfigFormatError
 from .arch import FabricSpec
 
 
@@ -22,13 +23,10 @@ class FabricOptions:
 
     spec           — the target array (auto-grown per variant when needed).
     backend        — annealing engine: "jax" (batched chains) | "python".
-    hpwl_backend   — placement cost kernel: "jnp" | "pallas"
-                     (:func:`repro.kernels.pnr_cost.hpwl_pallas`, interpret
-                     mode on the ``cpu`` platform only).
     score_mode     — move scoring: "delta" (incremental — rescore only the
                      nets the swap touches; the default and the only mode
                      that scales past ~32x32) | "full" (recompute every
-                     net per move; debug fallback — bit-identical
+                     net per move; the reference — bit-identical
                      placements at equal seeds).
     chains/sweeps/seed — annealing budget and determinism.
     simulate       — run the modulo scheduler + cycle-accurate simulator on
@@ -61,7 +59,6 @@ class FabricOptions:
 
     spec: Optional[FabricSpec] = None
     backend: str = "jax"
-    hpwl_backend: str = "jnp"
     score_mode: str = "delta"
     chains: int = 16
     sweeps: int = 32
@@ -100,6 +97,13 @@ class FabricOptions:
     @staticmethod
     def from_dict(d: Dict[str, Any]) -> "FabricOptions":
         d = dict(d)
+        # a removed field: blobs that carry it still parse at the one
+        # value the remaining placer computes
+        kernel = d.pop("hpwl_backend", "jnp")
+        if kernel != "jnp":
+            raise ConfigFormatError(
+                f"FabricOptions field 'hpwl_backend' was removed: only "
+                f"'jnp' is still read, got {kernel!r}")
         spec = d.pop("spec", None)
         known = {f.name for f in fields(FabricOptions)} - {"spec"}
         unknown = set(d) - known

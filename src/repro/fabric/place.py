@@ -7,19 +7,20 @@ total half-perimeter wirelength.  Two engines share one lowering:
 
 * ``backend="python"`` — the classic single-chain annealer with incremental
   per-net cost updates (the reference path);
-* ``backend="jax"`` — C independent chains annealed in lockstep, one
-  ``lax.fori_loop`` step proposing one move per chain and scoring it with
-  the HPWL kernels (:mod:`repro.kernels.pnr_cost`).  On accelerators the
-  whole sweep stays on-device.
+* ``backend="jax"`` — :func:`anneal_jax_batch`: the chains of one or many
+  problems annealed in lockstep, one ``lax.fori_loop`` step proposing one
+  move per chain and scoring it with the HPWL kernels
+  (:mod:`repro.kernels.pnr_cost`).  On accelerators the whole sweep stays
+  on-device.  A single problem anneals as a group of one, so it gets the
+  placement a batched dispatch gives it under the same nonce.
 
 Move scoring (``score_mode``): a swap touches only the nets incident to
-the two swapped entities, so the default ``"delta"`` mode carries the
-per-net cost vector through the loop state and rescores just those ≤2K
-nets per move (O(K·D) instead of O(N·D)); ``"full"`` recomputes every
-net's HPWL per move and is kept as the debug fallback.  Both modes see
-identical move schedules and — HPWL values being exactly-representable
-integers — compute bit-identical costs, so they accept/reject the same
-moves and return bit-identical placements for equal seeds.
+the two swapped entities, so the default ``"delta"`` mode rescores just
+those nets' costs per move; ``"full"`` recomputes every net's HPWL per
+move and is kept as the reference for it.  Both modes see identical move
+schedules and — HPWL values being exactly-representable integers —
+compute bit-identical costs, so they accept/reject the same moves and
+return bit-identical placements for equal seeds.
 
 PE cells live on the rows x cols grid, I/O cells on the perimeter ring;
 moves never cross the two classes, so every intermediate state is legal by
@@ -32,7 +33,7 @@ import functools
 import math
 import random as _random
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -41,9 +42,9 @@ from .arch import Coord, FabricSpec
 from .netlist import Netlist
 
 __all__ = ["PlacementProblem", "Placement", "HierPlacement", "lower",
-           "net_incidence", "anneal_python", "anneal_jax",
-           "anneal_jax_batch", "place", "place_hierarchical",
-           "batch_signature"]
+           "net_incidence", "anneal_python", "anneal_jax_batch",
+           "anneal_chains", "chain_placements", "place",
+           "place_hierarchical", "batch_signature"]
 
 
 @dataclass
@@ -229,158 +230,8 @@ def anneal_python(p: PlacementProblem, *, seed: int = 0, sweeps: int = 48,
 
 
 # ---------------------------------------------------------------------------
-# JAX batched chains
+# JAX batched chains: one or many problems in one dispatch
 # ---------------------------------------------------------------------------
-@functools.lru_cache(maxsize=64)
-def _build_annealer(steps: int, n_pe_c: int, n_io_c: int,
-                    n_pe_s: int, n_io_s: int, t0: float, t1: float,
-                    hpwl_backend: str = "jnp", score_mode: str = "delta"):
-    """Compile one batched annealer per static problem shape.
-
-    Caching here (rather than a fresh ``jax.jit`` per call) is what makes a
-    DSE sweep cheap: every variant of the same fabric reuses the program.
-
-    hpwl_backend selects the move-scoring kernel family: ``"jnp"`` (jitted
-    jax.numpy reductions) or ``"pallas"`` (the Pallas kernels from
-    :mod:`repro.kernels.pnr_cost`, compiled on TPU and interpreted on CPU,
-    as :func:`repro.kernels.default_interpret` decides).  score_mode
-    selects full recompute (``"full"``, O(N·D) per move) or incremental
-    rescoring of only the touched nets (``"delta"``, O(K·D) per move).  All
-    four combinations compute identical HPWL, so chains accept identical
-    move sequences and return identical placements.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from ..kernels.pnr_cost import (hpwl, hpwl_delta, hpwl_delta_pallas,
-                                    hpwl_pallas, net_hpwl)
-
-    if hpwl_backend == "pallas":
-        score = hpwl_pallas
-    elif hpwl_backend == "jnp":
-        score = hpwl
-    else:
-        raise ValueError(f"unknown hpwl_backend {hpwl_backend!r}")
-    if score_mode not in ("delta", "full"):
-        raise ValueError(f"unknown score_mode {score_mode!r}")
-
-    n_real = n_pe_c + n_io_c
-    p_pe = n_pe_c / n_real
-    temps = t0 * (t1 / t0) ** (jnp.arange(steps, dtype=jnp.float32) / steps)
-
-    def chain(key, slot_of0, slot_xy, net_pins, net_mask, ent_nets):
-        n_nets = net_pins.shape[0]
-
-        # draw the whole move schedule up front: one RNG call per stream
-        # instead of several threefry hashes inside every loop step
-        k1, k2, k3, k4, k5, k6 = jax.random.split(key, 6)
-        pick_pe = jax.random.uniform(k1, (steps,)) < p_pe
-        a = jnp.where(pick_pe,
-                      jax.random.randint(k2, (steps,), 0, max(1, n_pe_c)),
-                      n_pe_s + jax.random.randint(k3, (steps,), 0,
-                                                  max(1, n_io_c)))
-        t = jnp.where(pick_pe,
-                      jax.random.randint(k4, (steps,), 0, n_pe_s),
-                      n_pe_s + jax.random.randint(k5, (steps,), 0, n_io_s))
-        log_u = jnp.log(jax.random.uniform(k6, (steps,), minval=1e-12))
-
-        def accept_and_track(i, accept, cand, new, state_rest):
-            slot_of, cur, best_slot, best = state_rest
-            slot_of = jnp.where(accept, cand, slot_of)
-            cur = jnp.where(accept, new, cur)
-            improved = cur < best
-            best_slot = jnp.where(improved, slot_of, best_slot)
-            best = jnp.where(improved, cur, best)
-            return slot_of, cur, best_slot, best
-
-        if score_mode == "full":
-            def cost(slot_of):
-                return score(slot_xy[slot_of], net_pins, net_mask)
-
-            def step(i, state):
-                slot_of, cur, best_slot, best = state
-                ai, ti = a[i], t[i]
-                b = jnp.argmax(slot_of == ti)   # occupant of target slot
-                cand = slot_of.at[ai].set(slot_of[b]).at[b].set(slot_of[ai])
-                new = cost(cand)
-                accept = (new <= cur) | (log_u[i] * temps[i] < cur - new)
-                return accept_and_track(i, accept, cand, new, state)
-
-            c0 = cost(slot_of0)
-            _, _, best_slot, best = jax.lax.fori_loop(
-                0, steps, step, (slot_of0, c0, slot_of0, c0))
-            return best_slot, best
-
-        # -- delta mode: per-net cost vector rides in the loop state -------
-        k2_ = ent_nets.shape[1] * 2
-        dup_tri = jnp.tril(jnp.ones((k2_, k2_), bool), k=-1)
-
-        def step(i, state):
-            slot_of, pnc, cur, best_slot, best = state
-            ai, ti = a[i], t[i]
-            b = jnp.argmax(slot_of == ti)       # occupant of target slot
-            cand = slot_of.at[ai].set(slot_of[b]).at[b].set(slot_of[ai])
-            # nets incident to either swapped entity, deduped so a net
-            # touching both contributes its delta exactly once
-            tn = jnp.concatenate([ent_nets[ai], ent_nets[b]])
-            dup = jnp.any((tn[:, None] == tn[None, :]) & dup_tri, axis=1)
-            tn = jnp.where(dup, n_nets, tn)
-            if hpwl_backend == "pallas":
-                new_vals, delta = hpwl_delta_pallas(
-                    slot_xy, slot_of, net_pins, net_mask, pnc, tn,
-                    ai, b)
-            else:
-                new_vals, delta = hpwl_delta(slot_xy, cand, net_pins,
-                                             net_mask, pnc, tn)
-            new = cur + delta
-            accept = (new <= cur) | (log_u[i] * temps[i] < cur - new)
-            pnc = jnp.where(accept,
-                            pnc.at[tn].set(new_vals, mode="drop"), pnc)
-            slot_of, cur, best_slot, best = accept_and_track(
-                i, accept, cand, new, (slot_of, cur, best_slot, best))
-            return slot_of, pnc, cur, best_slot, best
-
-        pnc0 = net_hpwl(slot_xy[slot_of0], net_pins, net_mask)
-        c0 = jnp.sum(pnc0)
-        _, _, _, best_slot, best = jax.lax.fori_loop(
-            0, steps, step, (slot_of0, pnc0, c0, slot_of0, c0))
-        return best_slot, best
-
-    return jax.jit(jax.vmap(chain, in_axes=(0, 0, None, None, None, None)))
-
-
-def anneal_jax(p: PlacementProblem, *, chains: int = 32, seed: int = 0,
-               sweeps: int = 48, t0: Optional[float] = None,
-               t1: float = 0.02, hpwl_backend: str = "jnp",
-               score_mode: str = "delta"
-               ) -> Tuple[np.ndarray, np.ndarray]:
-    """C independent chains; returns (slot_of (C, E), costs (C,))."""
-    import jax
-
-    n_real = p.n_pe_cells + p.n_io_cells
-    if n_real == 0:
-        e = np.tile(np.arange(p.n_entities, dtype=np.int32), (chains, 1))
-        return e, np.zeros((chains,), np.float32)
-    steps = max(1, sweeps * n_real)
-    t0 = _default_t0(p) if t0 is None else t0
-
-    run = _build_annealer(steps, p.n_pe_cells, p.n_io_cells,
-                          p.n_pe_slots, p.n_io_slots, float(t0), float(t1),
-                          hpwl_backend, score_mode)
-    rng = _random.Random(seed)
-    init = np.stack([_init_slots(p, rng) for _ in range(chains)])
-    keys = jax.random.split(jax.random.PRNGKey(seed), chains)
-    ent_nets = p.ent_nets if p.ent_nets is not None else net_incidence(
-        p.net_pins, p.net_mask, p.n_entities)
-    slots, costs = run(keys, init, p.slot_xy, p.net_pins, p.net_mask,
-                       ent_nets)
-    return np.asarray(slots), np.asarray(costs)
-
-
-# ---------------------------------------------------------------------------
-# Cross-problem batching: many (variant, app) placements in one dispatch
-# ---------------------------------------------------------------------------
-
 
 def batch_signature(p: PlacementProblem, sweeps: int) -> Tuple[int, ...]:
     """Static shape key two problems must share to ride one dispatch."""
@@ -396,15 +247,13 @@ CURVE_POINTS = 16
 
 @functools.lru_cache(maxsize=64)
 def _build_batch_annealer(s_pad: int, n_pad: int, d_pad: int, e_pad: int,
-                          k_pad: int, t1: float, hpwl_backend: str,
-                          score_mode: str, telemetry: bool = False,
-                          fixed: bool = False):
+                          k_pad: int, t1: float, score_mode: str,
+                          telemetry: bool = False, fixed: bool = False):
     """One compiled chain program for every problem of one bucket signature.
 
-    Unlike :func:`_build_annealer` (which bakes the cell/slot counts into
-    the program as static Python ints), the batched chain takes them as
-    *data* — so PE1 on camera and PE4 on conv can share a program as long
-    as their padded shapes land in the same buckets.  Moves are sampled by
+    The chain takes the cell and slot counts as *data*, not as static
+    Python ints — so PE1 on camera and PE4 on conv can share a program as
+    long as their padded shapes land in the same buckets.  Moves are sampled by
     scaling uniforms with the dynamic counts, the temperature schedule uses
     the dynamic per-problem step count, and steps beyond a problem's real
     budget are masked to rejects.
@@ -445,9 +294,6 @@ def _build_batch_annealer(s_pad: int, n_pad: int, d_pad: int, e_pad: int,
     from ..kernels.pnr_cost import (hpwl, hpwl_fixed, net_hpwl,
                                     net_hpwl_fixed, net_hpwl_pins)
 
-    if hpwl_backend != "jnp":
-        raise ValueError("anneal_jax_batch supports hpwl_backend='jnp' only "
-                         "(the pallas delta kernel scores one swap per call)")
     if score_mode not in ("delta", "full"):
         raise ValueError(f"unknown score_mode {score_mode!r}")
 
@@ -645,8 +491,7 @@ def anneal_jax_batch(problems: List[PlacementProblem], *, chains: int = 16,
     zero, dummy entities sit on dummy slots and are never proposed as
     moves) and all ``len(problems) x chains`` chains run as one vmapped
     ``fori_loop``.  Returns per problem ``(slot_of (C, E), costs (C,))``
-    with E the problem's real entity count — the same contract as
-    :func:`anneal_jax`.
+    with E the problem's real entity count.
 
     Each problem's chains draw from ``fold_in(PRNGKey(seed), nonce)`` with
     ``nonces[i]`` defaulting to ``i``.  Callers wanting placements that are
@@ -755,7 +600,7 @@ def anneal_jax_batch(problems: List[PlacementProblem], *, chains: int = 16,
                 base_key, nonces[i] & 0x7FFFFFFF), chains))
 
         run = _build_batch_annealer(s_pad, n_pad, d_pad, e_pad, k_pad,
-                                    float(t1), "jnp", score_mode,
+                                    float(t1), score_mode,
                                     bool(telemetry), has_fix)
 
         def flat(x):                 # (P, C, ...) -> (P*C, ...)
@@ -789,53 +634,72 @@ def anneal_jax_batch(problems: List[PlacementProblem], *, chains: int = 16,
                 for i, p in enumerate(problems)]
 
 
+def anneal_chains(p: PlacementProblem, *, backend: str = "jax",
+                  chains: int = 32, sweeps: int = 48, seed: int = 0,
+                  t0: Optional[float] = None, t1: float = 0.02,
+                  score_mode: str = "delta", nonce: int = 0,
+                  max_states: Optional[int] = None, metrics=None
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+    """Anneal one problem; returns every chain's ``(slot_of (C, E),
+    costs (C,))``.
+
+    ``backend="jax"`` runs :func:`anneal_jax_batch` on a group of one,
+    its chains keyed by ``nonce``: by the batched annealer's grouping
+    independence, the chains any dispatch gives this problem under the
+    same nonce.  ``backend="python"`` runs :func:`anneal_python` once per
+    chain, chain ``c`` seeded ``seed + c``; the nonce does not enter it.
+    ``max_states`` bounds the state budget (chains x sweeps x cells)
+    before anything runs, on both backends.
+    """
+    if score_mode not in ("delta", "full"):
+        raise ValueError(f"unknown score_mode {score_mode!r}")
+    if backend == "jax":
+        (slots, costs), = anneal_jax_batch(
+            [p], chains=chains, seed=seed, sweeps=sweeps, t0=t0, t1=t1,
+            score_mode=score_mode, nonces=[nonce], metrics=metrics,
+            max_states=max_states)
+        return slots, costs
+    if backend != "python":
+        raise ValueError(f"unknown backend {backend!r}")
+    check_anneal_budget(p, chains, sweeps, max_states, metrics=metrics)
+    # the python reference is inherently incremental; score_mode only
+    # selects between the jax engine's two scoring programs
+    runs = [anneal_python(p, seed=seed + c, sweeps=sweeps, t0=t0, t1=t1)
+            for c in range(chains)]
+    return (np.stack([s for s, _ in runs]),
+            np.asarray([c for _, c in runs], np.float32))
+
+
+def chain_placements(p: PlacementProblem, slots: np.ndarray,
+                     costs: np.ndarray, *, backend: str,
+                     sweeps: int) -> Iterator[Placement]:
+    """Each chain's placement, cheapest first (ties by chain index, so the
+    first is the ``argmin`` chain); built one at a time as consumed."""
+    chain_costs = [float(c) for c in costs]
+    for chain in np.argsort(costs, kind="stable"):
+        coords: Dict[str, Coord] = {}
+        for idx, name in enumerate(p.cell_names):
+            x, y = p.slot_xy[slots[chain][p.entity_of(idx)]]
+            coords[name] = (int(x), int(y))
+        yield Placement(coords=coords, cost=chain_costs[chain],
+                        backend=backend, chains=len(chain_costs),
+                        sweeps=sweeps, chain_costs=chain_costs)
+
+
 def place(netlist: Netlist, spec: FabricSpec, *, backend: str = "jax",
           chains: int = 32, sweeps: int = 48, seed: int = 0,
           t0: Optional[float] = None, t1: float = 0.02,
-          hpwl_backend: str = "jnp", score_mode: str = "delta",
+          score_mode: str = "delta", nonce: int = 0,
           max_states: Optional[int] = None) -> Placement:
-    """Anneal and return the best chain's placement.
-
-    ``max_states`` bounds the anneal state budget (chains x sweeps x
-    entities) exactly like the batched path — the serial fallback must
-    not silently out-spend the budget the grouped dispatch enforces.
-    """
-    if hpwl_backend not in ("jnp", "pallas"):
-        raise ValueError(f"unknown hpwl_backend {hpwl_backend!r}")
-    if score_mode not in ("delta", "full"):
-        raise ValueError(f"unknown score_mode {score_mode!r}")
+    """Anneal (:func:`anneal_chains`) and return the cheapest chain's
+    placement."""
     p = lower(netlist, spec)
-    check_anneal_budget(p, chains, sweeps, max_states)
-
-    if backend == "python":
-        if hpwl_backend != "jnp":
-            raise ValueError(
-                "hpwl_backend applies to the jax annealer only; the python "
-                "reference scores moves without the HPWL kernel")
-        # the python reference is inherently incremental; score_mode only
-        # selects between the jax engine's two scoring programs
-        chain_results = [anneal_python(p, seed=seed + c, sweeps=sweeps,
-                                       t0=t0, t1=t1)
-                         for c in range(chains)]
-        slots = np.stack([s for s, _ in chain_results])
-        costs = np.asarray([c for _, c in chain_results], np.float32)
-    elif backend == "jax":
-        slots, costs = anneal_jax(p, chains=chains, seed=seed, sweeps=sweeps,
-                                  t0=t0, t1=t1, hpwl_backend=hpwl_backend,
-                                  score_mode=score_mode)
-    else:
-        raise ValueError(f"unknown backend {backend!r}")
-
-    best = int(np.argmin(costs))
-    slot_of = slots[best]
-    coords: Dict[str, Coord] = {}
-    for idx, name in enumerate(p.cell_names):
-        ent = p.entity_of(idx)
-        x, y = p.slot_xy[slot_of[ent]]
-        coords[name] = (int(x), int(y))
-    return Placement(coords=coords, cost=float(costs[best]), backend=backend,
-                     chains=chains, sweeps=sweeps,
-                     chain_costs=[float(c) for c in costs])
+    slots, costs = anneal_chains(p, backend=backend, chains=chains,
+                                 sweeps=sweeps, seed=seed, t0=t0, t1=t1,
+                                 score_mode=score_mode, nonce=nonce,
+                                 max_states=max_states)
+    return next(chain_placements(p, slots, costs, backend=backend,
+                                 sweeps=sweeps))
 
 
 # ---------------------------------------------------------------------------

@@ -13,8 +13,9 @@ from __future__ import annotations
 
 import heapq
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, Iterable, List, Optional, Set, Tuple
 
+from ..errors import Unroutable
 from .arch import Coord, Edge, FabricSpec, manhattan
 from .netlist import Netlist
 from .place import Placement
@@ -177,3 +178,48 @@ def route_nets(netlist: Netlist, placement: Placement, spec: FabricSpec,
                        iterations=iters,
                        edge_usage={e: u for e, u in usage.items() if u},
                        ripups=ripups)
+
+
+def route_first_legal(netlist: Netlist, placements: Iterable[Placement],
+                      spec: FabricSpec, *, label: str, metrics=None
+                      ) -> Tuple[Placement, RouteResult]:
+    """Route candidate placements in the order given and serve the first
+    whose routing fits every channel's track count.
+
+    Callers give an anneal's chains cheapest first
+    (:func:`repro.fabric.place.chain_placements`), so a costlier chain is
+    served only when every cheaper one's routing leaves a channel over
+    capacity.  When no candidate routes, raises
+    :class:`~repro.errors.Unroutable` with the fewest tracks over
+    capacity that any try left, counted as ``pnr.route.unroutable``.  A
+    served pair observes ``pnr.route.ripups`` (nets rerouted, over its
+    tries) and ``pnr.route.fell_back`` (1 when a candidate other than the
+    first was served) in ``metrics``.  The enclosing span (``pnr.pair`` on
+    the explore path) gets the last routing's ``iterations`` and
+    ``overflow``, and ``ripups``, ``chain_rank`` and ``tries``.
+    """
+    from ..obs import current_span
+
+    ripups, overflows = 0, []
+    for rank, placement in enumerate(placements):
+        routes = route_nets(netlist, placement, spec)
+        ripups += routes.ripups
+        overflows.append(routes.overflow)
+        if routes.success:
+            break
+    sp = current_span()
+    if sp is not None:
+        sp.attrs.update(iterations=routes.iterations,
+                        overflow=routes.overflow, ripups=ripups,
+                        chain_rank=rank, tries=rank + 1)
+    if not routes.success:
+        if metrics is not None:
+            metrics.inc("pnr.route.unroutable")
+        raise Unroutable(
+            f"{label}: none of {len(overflows)} chains routes on "
+            f"{spec.summary()}; fewest tracks over capacity "
+            f"{min(overflows)}", min(overflows))
+    if metrics is not None:
+        metrics.observe("pnr.route.ripups", ripups)
+        metrics.observe("pnr.route.fell_back", int(rank > 0))
+    return placement, routes

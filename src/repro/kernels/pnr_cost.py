@@ -21,8 +21,8 @@ incident to the two swapped entities, so the annealer's hot loop rescopes
 those ≤2K nets instead of all N:
 
 * :func:`hpwl_delta` — jnp path: gather only the touched nets' pins under
-  the candidate permutation and rescore them (the serial annealer; the
-  batched one, :func:`repro.fabric.place._build_batch_annealer`, carries
+  the candidate permutation and rescore them (no placer calls it: the
+  annealer, :func:`repro.fabric.place._build_batch_annealer`, carries
   pin coordinates instead and rescores them with :func:`net_hpwl_pins`,
   with no gather or scatter in its loop);
 * :func:`hpwl_delta_pallas` — fused Pallas variant: pre-swap pin

@@ -32,7 +32,6 @@ def build_sim(dp: Datapath, mapping: Mapping, app: Graph,
               spec: Optional[FabricSpec] = None, *,
               place_backend: str = "jax", chains: int = 8,
               sweeps: int = 24, seed: int = 0,
-              hpwl_backend: str = "jnp",
               pnr: Optional[PnRResult] = None,
               max_ii: Optional[int] = None,
               budget_factor: int = 8
@@ -46,8 +45,7 @@ def build_sim(dp: Datapath, mapping: Mapping, app: Graph,
     if pnr is None:
         pnr = place_and_route(dp, mapping, app, spec,
                               backend=place_backend, chains=chains,
-                              sweeps=sweeps, seed=seed,
-                              hpwl_backend=hpwl_backend)
+                              sweeps=sweeps, seed=seed)
     sched = modulo_schedule(pnr.netlist, pnr.placement, pnr.routes,
                             pnr.spec, max_ii=max_ii,
                             budget_factor=budget_factor)

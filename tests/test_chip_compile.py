@@ -63,7 +63,7 @@ def test_batch_annealer_compiles_32x32(chip):
 
     s_pad, n_pad, d_pad, e_pad, k_pad = batch_signature(_synthetic(32), 32)
     run = _build_batch_annealer(s_pad, n_pad, d_pad, e_pad, k_pad, 0.02,
-                                "jnp", "delta")
+                                "delta")
     rows = 4 * CHAINS                         # 4 problems x their chains
     compiled = run.lower(
         _spec(chip, (rows, 2), jnp.uint32),
@@ -115,7 +115,7 @@ def test_batch_annealer_loop_body_has_no_gather_or_scatter(chip):
 
     s_pad, n_pad, d_pad, e_pad, k_pad = 2048, 64, 2, 512, 4
     run = _build_batch_annealer(s_pad, n_pad, d_pad, e_pad, k_pad, 0.02,
-                                "jnp", "delta")
+                                "delta")
     rows = 6 * CHAINS
     compiled = run.lower(
         _spec(chip, (rows, 2), jnp.uint32),

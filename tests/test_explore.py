@@ -12,8 +12,8 @@ from repro.core import MiningConfig, mine_and_rank, specialize_per_app
 from repro.core.dse import DSEResult, PEVariant, build_variants, \
     evaluate_variants
 from repro.core.costmodel import AppCost
-from repro.explore import (ExploreConfig, ExploreRecord, Explorer,
-                           RECORD_SCHEMA, from_jsonl, to_jsonl)
+from repro.explore import (ConfigFormatError, ExploreConfig, ExploreRecord,
+                           Explorer, RECORD_SCHEMA, from_jsonl, to_jsonl)
 from repro.fabric import FabricOptions, FabricSpec
 from repro.graphir import trace_scalar
 
@@ -84,7 +84,7 @@ def test_explore_config_json_round_trip():
         per_app_subgraphs=3, domain_name="PE_X",
         fabric=FabricOptions(spec=FabricSpec(rows=6, cols=5), chains=3,
                              sweeps=9, seed=7, simulate=True),
-        pnr_batch="serial", sim_batch="serial")
+        sim_batch="serial")
     blob = json.dumps(cfg.to_dict())
     assert ExploreConfig.from_dict(json.loads(blob)) == cfg
     # no-fabric config round-trips too
@@ -96,11 +96,30 @@ def test_explore_config_json_round_trip():
         ExploreConfig.from_dict(cfg2.to_dict() | {"bogus": 1})
 
 
+@pytest.mark.parametrize("field, value, reads", [
+    ("pnr_batch", "grouped", True), ("pnr_batch", "serial", False),
+    ("hpwl_backend", "jnp", True), ("hpwl_backend", "pallas", False)])
+def test_config_blob_with_a_removed_field(field, value, reads):
+    """Blobs written before ``pnr_batch`` and ``fabric.hpwl_backend`` were
+    removed parse at the one value the remaining path computes, and are
+    refused, in one line naming the field, at any other."""
+    cfg = ExploreConfig(mining=FAST, fabric=FabricOptions(
+        spec=FabricSpec(rows=4, cols=4), chains=2))
+    blob = cfg.to_dict()
+    assert field not in blob and field not in blob["fabric"]
+    old = json.loads(json.dumps(blob))
+    (old["fabric"] if field == "hpwl_backend" else old)[field] = value
+    if reads:
+        assert ExploreConfig.from_dict(old) == cfg
+    else:
+        with pytest.raises(ConfigFormatError, match=f"'{field}'") as e:
+            ExploreConfig.from_dict(old)
+        assert "\n" not in str(e.value)
+
+
 def test_config_rejects_bad_values():
     with pytest.raises(ValueError, match="mode"):
         ExploreConfig(mode="nope")
-    with pytest.raises(ValueError, match="pnr_batch"):
-        ExploreConfig(pnr_batch="nope")
     with pytest.raises(ValueError, match="sim_batch"):
         ExploreConfig(sim_batch="nope")
     with pytest.raises(ValueError, match="rank_mode"):
@@ -178,7 +197,7 @@ def test_grouped_pnr_matches_serial_structure_and_is_deterministic():
     cfg = ExploreConfig(mode="per_app",
                         mining=MiningConfig(min_support=2,
                                             max_pattern_nodes=5),
-                        max_merge=2, fabric=fabric, pnr_batch="grouped")
+                        max_merge=2, fabric=fabric)
     ex = Explorer(apps, cfg)
     grouped = ex.pnr()
     assert ex.stats["pnr_dispatch"] >= 1
@@ -192,7 +211,9 @@ def test_grouped_pnr_matches_serial_structure_and_is_deterministic():
             for p in grouped.values()}
     assert ex.metrics.counter("pnr_dispatch") == len(sigs)
     assert ex.metrics.counter("memo.miss.pnr") == len(grouped)
-    serial = ex.with_config(pnr_batch="serial").pnr()
+    # the per-pair loop, which the python backend runs
+    serial = ex.with_config(
+        fabric=dataclasses.replace(fabric, backend="python")).pnr()
     assert set(grouped) == set(serial)
     for pair in grouped:
         g, s = grouped[pair], serial[pair]

@@ -8,7 +8,7 @@ from repro.core import baseline_datapath, map_application
 from repro.core.dse import app_ops
 from repro.fabric import (FabricSpec, extract_netlist, place,
                           place_and_route, route_nets, synthetic_netlist)
-from repro.fabric.place import anneal_jax, anneal_python, lower, \
+from repro.fabric.place import anneal_jax_batch, anneal_python, lower, \
     net_incidence
 from repro.kernels.pnr_cost import (HPWL_BLOCK_NETS, hpwl, hpwl_batched,
                                     hpwl_delta, hpwl_delta_pallas,
@@ -188,8 +188,10 @@ def test_jax_annealer_improves_over_initial(harris):
 
     _, _, _, nl = harris
     problem = lower(nl, SPEC)
-    slots, costs = anneal_jax(problem, chains=4, seed=0, sweeps=8)
-    # reconstruct the chains' initial states (same seed stream as anneal_jax)
+    (slots, costs), = anneal_jax_batch([problem], chains=4, seed=0,
+                                       sweeps=8)
+    # reconstruct the chains' initial states (same seed stream as
+    # anneal_jax_batch)
     rng = random.Random(0)
     init_costs = []
     for _ in range(4):
@@ -270,17 +272,15 @@ def test_hpwl_delta_matches_full_recompute(harris, kernel):
 
 
 def test_delta_full_bit_identical_16x16():
-    """Deterministic regression: at 16x16 every (score_mode, hpwl_backend)
-    combination accepts the same move sequence and returns bit-identical
-    placements and costs."""
+    """Deterministic regression: at 16x16 both score modes accept the same
+    move sequence and return bit-identical placements and costs."""
     spec = FabricSpec(rows=16, cols=16)
     p = lower(synthetic_netlist(spec, seed=2), spec)
     runs = {}
     for mode in ("delta", "full"):
-        for hb in ("jnp", "pallas"):
-            runs[(mode, hb)] = anneal_jax(p, chains=2, seed=7, sweeps=2,
-                                          hpwl_backend=hb, score_mode=mode)
-    ref_slots, ref_costs = runs[("full", "jnp")]
+        (runs[mode],) = anneal_jax_batch([p], chains=2, seed=7, sweeps=2,
+                                         score_mode=mode)
+    ref_slots, ref_costs = runs["full"]
     for key, (slots, costs) in runs.items():
         assert np.array_equal(slots, ref_slots), key
         assert np.array_equal(costs, ref_costs), key

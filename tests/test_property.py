@@ -127,16 +127,16 @@ def test_property_delta_equals_full_recompute(net_seed, fill, fanout,
     exactly the moves the full-recompute annealer accepts, so placements
     and costs come back bit-identical."""
     from repro.fabric import FabricSpec, synthetic_netlist
-    from repro.fabric.place import anneal_jax, lower
+    from repro.fabric.place import anneal_jax_batch, lower
 
     spec = FabricSpec(rows=4, cols=4)
     nl = synthetic_netlist(spec, fill=fill, seed=net_seed,
                            max_fanout=fanout)
     p = lower(nl, spec)
-    s_d, c_d = anneal_jax(p, chains=2, seed=anneal_seed, sweeps=3,
-                          score_mode="delta")
-    s_f, c_f = anneal_jax(p, chains=2, seed=anneal_seed, sweeps=3,
-                          score_mode="full")
+    (s_d, c_d), = anneal_jax_batch([p], chains=2, seed=anneal_seed,
+                                   sweeps=3, score_mode="delta")
+    (s_f, c_f), = anneal_jax_batch([p], chains=2, seed=anneal_seed,
+                                   sweeps=3, score_mode="full")
     assert np.array_equal(s_d, s_f)
     assert np.array_equal(c_d, c_f)
 

@@ -1,6 +1,8 @@
 """Only legal routes are served: the grouped placer routes its chains in
 cost order until one fits the channels' track counts, and a pair that no
-chain routes fails as ``Unroutable`` instead of becoming a record.
+chain routes fails as ``Unroutable`` instead of becoming a record.  A
+pair placed alone (``place_and_route`` under the pair's nonce, as the
+fault-isolation retry places it) gets what its group gives it.
 
 The configurations are the chip benchmark's own (``ml16`` and
 ``image_ip``), with simulation off, mined once per module."""
@@ -13,7 +15,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from repro import obs
+from repro import faultinject, obs
 from repro.apps import image_graphs, ml_graphs
 from repro.core import MiningConfig
 from repro.core.dse import app_ops
@@ -258,3 +260,90 @@ def test_the_serial_place_and_route_refuses_an_illegal_routing():
     legal = place_and_route(dp, mapping, app, FabricSpec(2, 2),
                             chains=2, sweeps=4)
     assert legal.routes.success and _illegal(legal) == 0
+
+
+# ---------------------------------------------------------------------------
+# one chain rule: a pair placed alone is the pair placed in its group
+# ---------------------------------------------------------------------------
+def _alone(item, f: FabricOptions):
+    pe_name, dp, mapping, app, nonce = item
+    return place_and_route(dp, mapping, app, f.spec, backend=f.backend,
+                           chains=f.chains, sweeps=f.sweeps, seed=f.seed,
+                           nonce=nonce, pe_name=pe_name,
+                           score_mode=f.score_mode,
+                           max_states=f.anneal_max_states)
+
+
+@pytest.mark.parametrize("name, seed, pair", [
+    ("ml16", 3, ("PE2", "conv")), ("image_ip", 7, ("PE_IP", "harris"))])
+def test_place_and_route_with_the_pairs_nonce_is_its_grouped_result(
+        name, seed, pair, request, monkeypatch):
+    ex = _at_seed(request.getfixturevalue(name), seed)
+    ex.forget("pnr")
+    _, items = _capture(monkeypatch)
+    try:
+        grouped = ex.pnr()[pair]
+    finally:
+        ex.forget("pnr")
+    (item,) = [it for it in items if (it[0], it[2].app_name) == pair]
+    alone = _alone(item, ex.config.fabric)
+    assert alone.spec == grouped.spec
+    assert alone.placement == grouped.placement
+    assert alone.routes == grouped.routes
+    assert alone.cost == grouped.cost
+
+
+def test_the_witness_placed_alone_serves_the_same_chain(image_ip,
+                                                        monkeypatch):
+    ex = _at_seed(image_ip, WITNESS_SEED)
+    ex.forget("pnr")
+    _, items = _capture(monkeypatch)
+    obs.disable_tracing()
+    tracer = obs.enable_tracing()
+    try:
+        grouped = ex.pnr()[("PE_IP", "camera")]
+        (item,) = [it for it in items if it[2].app_name == "camera"]
+        with obs.span("alone"):
+            alone = _alone(item, ex.config.fabric)
+    finally:
+        obs.disable_tracing()
+        ex.forget("pnr")
+    ranks = {sp.name: sp.attrs["chain_rank"]
+             for sp, _, _ in tracer.iter_spans()
+             if sp.name == "alone" or (sp.name == "pnr.pair"
+                                       and sp.attrs["app"] == "camera")}
+    assert ranks["alone"] == ranks["pnr.pair"] > 0
+    assert alone.placement == grouped.placement
+    assert alone.routes == grouped.routes and alone.routes.success
+
+
+def test_a_retried_pair_gets_the_record_its_group_gives(image_ip):
+    ex = _at_seed(image_ip, 7)
+    ex.forget("pnr")
+    clean = [json.dumps(r.to_dict()) for r in ex.run().records()]
+    ex.forget("pnr")
+    faultinject.arm("pnr:exc:0:app=harris")    # harris falls out of its
+    try:                                       # group and retries alone
+        res = ex.run()
+    finally:
+        faultinject.disarm_all()
+        ex.forget("pnr")
+    assert res.clean
+    assert ex.metrics.counter("isolate.retry.pnr") >= 1
+    assert [json.dumps(r.to_dict()) for r in res.records()] == clean
+
+
+@pytest.mark.parametrize("name", ["ml16", "image_ip"])
+def test_the_benchmark_config_blobs_parse(name):
+    """The chip benchmark sends its configurations' blobs unchanged; they
+    carry the removed ``pnr_batch`` and ``fabric.hpwl_backend`` at the
+    values still read."""
+    blob = json.loads((CONFIGS / f"{name}.json").read_text())["explore"]
+    assert blob["pnr_batch"] == "grouped"
+    assert blob["fabric"]["hpwl_backend"] == "jnp"
+    cfg = ExploreConfig.from_dict(blob)
+    assert (cfg.fabric.backend, cfg.pnr_mode) == ("jax", "flat")
+    rest = {k: v for k, v in blob.items() if k != "pnr_batch"}
+    rest["fabric"] = {k: v for k, v in blob["fabric"].items()
+                      if k != "hpwl_backend"}
+    assert cfg.to_dict() == rest

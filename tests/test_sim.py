@@ -11,8 +11,7 @@ import pytest
 from repro.apps import image_graphs, ml_graphs
 from repro.core import baseline_datapath, map_application
 from repro.core.dse import PEVariant, app_ops, evaluate_variants
-from repro.fabric import (FabricOptions, FabricSpec, extract_netlist, place,
-                          place_and_route)
+from repro.fabric import FabricOptions, FabricSpec, place_and_route
 from repro.sim import (build_sim, check_against_interp, min_ii,
                        modulo_schedule, random_inputs, simulate,
                        verify_mapping)
@@ -216,21 +215,21 @@ def test_alu_step_rejects_unknown_ops():
 
 
 # ---------------------------------------------------------------------------
-# placer: pallas HPWL backend behind the switch
+# FabricOptions blobs: the removed hpwl_backend field
 # ---------------------------------------------------------------------------
-def test_place_hpwl_pallas_backend_matches_jnp():
-    app = image_graphs()["gaussian"]
-    dp, mapping = _flow("gaussian", app)
-    nl = extract_netlist(mapping, app, SPEC)
-    a = place(nl, SPEC, backend="jax", chains=2, sweeps=4, seed=5,
-              hpwl_backend="jnp")
-    b = place(nl, SPEC, backend="jax", chains=2, sweeps=4, seed=5,
-              hpwl_backend="pallas")
-    # identical cost kernel values -> identical accepted move sequences
-    assert a.coords == b.coords and a.cost == b.cost
-    with pytest.raises(ValueError):
-        place(nl, SPEC, backend="jax", chains=1, sweeps=2,
-              hpwl_backend="nope")
+@pytest.mark.parametrize("kernel, reads", [("jnp", True), ("pallas", False)])
+def test_fabric_options_blob_with_removed_hpwl_backend(kernel, reads):
+    from repro.errors import ConfigFormatError
+
+    blob = FabricOptions(spec=SPEC, chains=3).to_dict()
+    assert "hpwl_backend" not in blob
+    old = blob | {"hpwl_backend": kernel}
+    if reads:
+        assert FabricOptions.from_dict(old) == FabricOptions.from_dict(blob)
+    else:
+        with pytest.raises(ConfigFormatError, match="'hpwl_backend'") as e:
+            FabricOptions.from_dict(old)
+        assert "\n" not in str(e.value)
 
 
 # ---------------------------------------------------------------------------
